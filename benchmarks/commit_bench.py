@@ -115,4 +115,6 @@ def main(iters: int = 5) -> None:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     main()

@@ -44,4 +44,6 @@ def main(rounds: int = 10, out_path: str = "experiments/fig2.json"):
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     main()

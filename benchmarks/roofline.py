@@ -65,4 +65,6 @@ def main(dir_: str = "experiments/dryrun",
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     main()

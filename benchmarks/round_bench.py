@@ -23,14 +23,15 @@ examples) is measured and reported alongside.
 A third variant — ``mesh_shards=8`` — runs the SAME fused engine with the
 parameter arena row-sharded over an 8-device client mesh
 (`repro.runtime.arena.ShardedParamArena`): per-device population state drops
-to n/8 rows while replay stays bit-identical (asserted).  The sharded run
-executes in a SUBPROCESS that self-forces
+to n/8 rows while replay stays bit-identical (asserted).  On the CPU the
+sharded run executes in a SUBPROCESS that self-forces
 ``--xla_force_host_platform_device_count`` — forcing the device count in the
 main process would split the CPU thread pool and skew the legacy/engine
-timings this file has tracked since PR 3.  The cross-process block-hash /
-balance comparison therefore doubles as a replay gate across device
-topologies.  The cohort axis is sharded end-to-end (PR 7): each device
-trains its slice of the cohort and aggregation combines shard-local
+timings this file has tracked from its first version.  On an accelerator
+it runs in this process over the devices that exist, and fails when there
+are too few.  The block-hash / balance comparison therefore doubles as a
+replay gate across device topologies.  The cohort axis is sharded end-to-end: each
+device trains its slice of the cohort and aggregation combines shard-local
 partials with a fixed-order tree, so the sharded latency column measures
 real cohort-parallel execution on a forced CPU mesh (8 logical devices on
 one physical CPU); ``per_device_arena_bytes`` is the scaling headline.
@@ -211,13 +212,20 @@ def _sharded_run(n_clients: int, sample_frac: float, rounds: int,
                  eval_examples: int, mesh_shards: int,
                  strategy: str = "bfln") -> dict:
     """The mesh-sharded engine run — in-process when enough devices already
-    exist, otherwise via a ``--sharded-only`` subprocess that self-forces the
-    CPU device count (keeping THIS process single-device so the legacy and
-    engine timings stay comparable with the pre-mesh trajectory)."""
-    import jax
-    if mesh_shards <= len(jax.devices()):
+    exist, otherwise (CPU only) via a ``--sharded-only`` subprocess that
+    self-forces the CPU device count (keeping THIS process single-device so
+    the legacy and engine timings stay comparable with the pre-mesh
+    trajectory).  On an accelerator this process already holds the chips,
+    so a child could reach none: too few devices is an error there."""
+    n_dev = len(jax.devices())
+    if mesh_shards <= n_dev:
         return _run(True, n_clients, sample_frac, rounds, eval_examples,
                     mesh_shards, strategy)
+    if jax.default_backend() != "cpu":
+        raise RuntimeError(
+            f"the sharded case needs {mesh_shards} {jax.default_backend()} "
+            f"devices but {n_dev} exist; pass --mesh-shards {n_dev} or less "
+            f"(--mesh-shards 1 skips it)")
     payload = json.dumps({"n_clients": n_clients, "sample_frac": sample_frac,
                           "rounds": rounds, "eval_examples": eval_examples,
                           "strategy": strategy})
@@ -644,6 +652,8 @@ def main(n_clients: int = 1000, sample_frac: float = 0.10, rounds: int = 50,
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     p = argparse.ArgumentParser(description=__doc__)
     p.add_argument("--quick", action="store_true",
                    help="CI smoke: small population, few rounds, no heavy case")
@@ -662,7 +672,8 @@ if __name__ == "__main__":
     p.add_argument("--rounds", type=int, default=None)
     p.add_argument("--mesh-shards", type=int, default=8,
                    help="client-mesh width for the sharded case (1 disables; "
-                        "the needed CPU devices are forced in a subprocess)")
+                        "on the CPU the needed devices are forced in a "
+                        "subprocess)")
     p.add_argument("--sharded-only", default=None, metavar="JSON",
                    help="internal worker mode: run ONLY the sharded case for "
                         "the given case params and print its metrics as JSON")

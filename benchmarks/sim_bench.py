@@ -91,4 +91,6 @@ def main(quick: bool = True):
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     main(quick=False)

@@ -51,6 +51,8 @@ def main(full: bool = False, rounds: int = 12,
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--full", action="store_true")
     ap.add_argument("--rounds", type=int, default=12)

@@ -67,7 +67,11 @@ import numpy as np
 
 from repro.core.baselines import barrier_combine_inputs
 from repro.core.fl import local_train
-from repro.kernels.fingerprint import fingerprint_rows, format_digest
+from repro.kernels.fingerprint import (
+    fingerprint_rows,
+    fingerprint_rows_sharded,
+    format_digest,
+)
 from repro.obs import NULL_RECORDER
 from repro.runtime.arena import ArenaLayout, bitcast_u32
 
@@ -161,6 +165,15 @@ class RoundEngine:
                 return jax.lax.with_sharding_constraint(x, cshard)
         else:
             _rep = _shd = _csh = lambda x: x
+
+        def _fingerprint(rows):
+            """(m, N) fp32 rows -> (m, 2) residues.  On a mesh the kernel
+            runs per device under shard_map — Mosaic kernels cannot be
+            partitioned automatically."""
+            if sharding is None:
+                return fingerprint_rows(bitcast_u32(rows))
+            return fingerprint_rows_sharded(bitcast_u32(rows), sharding.mesh,
+                                            sharding.mesh.axis_names[0])
 
         def _pad0(x, pad):
             """Append ``pad`` zero slots along the leading (cohort) axis."""
@@ -266,7 +279,7 @@ class RoundEngine:
                                                   _rep(arrived_p), k)
                     agg = jax.tree.map(_rep, agg)
                 local_rows = layout.flatten(res.params)    # (k_pad, N) sharded
-                residues = fingerprint_rows(bitcast_u32(local_rows))[:k]
+                residues = _fingerprint(local_rows)[:k]
                 mean_loss = jnp.mean(res.mean_loss[:k])
                 prev_rows = rows[:k]
             else:
@@ -280,7 +293,9 @@ class RoundEngine:
                     agg = strategy.aggregate_cohort(res.params, cx, cy,
                                                     arrived)
                 local_rows = layout.flatten(res.params)
-                residues = fingerprint_rows(bitcast_u32(local_rows))
+                # pinned replicated: on a mesh the kernel's per-device row
+                # split would otherwise propagate back into training
+                residues = _fingerprint(_rep(local_rows))
                 mean_loss = jnp.mean(res.mean_loss)
                 prev_rows = rows
             new_rows = layout.flatten(agg.stacked_params)
@@ -315,7 +330,7 @@ class RoundEngine:
                     pad)
                 res = _train(layout.unflatten(rows), cx_p, cy_p, extras)
                 local_rows_p = layout.flatten(res.params)
-                residues = fingerprint_rows(bitcast_u32(local_rows_p))[:k]
+                residues = _fingerprint(local_rows_p)[:k]
                 local_rows = _rep(local_rows_p[:k])
                 mean_loss = jnp.mean(res.mean_loss[:k])
                 return local_rows, residues, mean_loss
@@ -323,7 +338,7 @@ class RoundEngine:
                                            cx, cy)
             res = _train(layout.unflatten(base_rows), cx, cy, extras)
             local_rows = layout.flatten(res.params)
-            residues = fingerprint_rows(bitcast_u32(local_rows))
+            residues = _fingerprint(_rep(local_rows))
             return local_rows, residues, jnp.mean(res.mean_loss)
 
         def _eval_cohort(cohort_rows, arrived, labels, ex, ey):

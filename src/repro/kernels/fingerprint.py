@@ -25,10 +25,19 @@ uint32 wraparound) and streamed through VMEM alongside the data, so the
 kernel is a pure VPU multiply-accumulate:
 
     grid (m_tiles, n_tiles); each program owns a (BM, 128) lane accumulator
-    and folds its (BM, BN) data/weight tiles as (BM, BN//128, 128) partial
-    sums.  The final 128-lane fold is exact because r^j already encodes the
-    lane offset (j = 128·t + l), so cross-lane combination is plain modular
-    addition — done in jnp on the tiny (m, 128) output.
+    per base, multiplies its whole (BM, BN) data tile by each (1, BN) weight
+    row, and folds the products into the accumulator with BN//128 elementwise
+    uint32 adds of 128-lane slices.  No reduction op appears in the kernel
+    body (Mosaic refuses reductions over unsigned integers), and the weight
+    rows are broadcast whole, at lane offset 0, never as lane slices.  The
+    final 128-lane fold is exact because r^j already encodes the lane offset
+    (j = 128·t + l), so cross-lane combination is plain modular addition —
+    done in jnp on the tiny (m, 128) output.
+
+On a device mesh (:func:`fingerprint_rows_sharded`) the kernel runs under
+``jax.shard_map`` over the client axis: each device fingerprints the rows
+it holds.  Mosaic kernels cannot be partitioned automatically, and each
+row's residues depend only on that row.
 
 Zero padding of the N axis is neutral by construction (0 · w = 0), so
 non-aligned N needs no masking.  This is a *fingerprint* (tamper-evidence
@@ -48,6 +57,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.sharding import PartitionSpec as P
 
 from repro.runtime.arena import ArenaLayout
 
@@ -90,11 +100,13 @@ def _fingerprint_kernel(x_ref, w_ref, out_ref, *, bn: int):
 
     x = x_ref[...]
     x = x ^ (x >> 16)                  # bit mix; mix(0) == 0 keeps padding neutral
-    bm = x.shape[0]
-    folds = x.reshape(bm, bn // 128, 128)
-    w = w_ref[...].reshape(2, bn // 128, 128)
-    acc1 = jnp.sum(folds * w[0][None], axis=1)     # (BM, 128), wraps mod 2^32
-    acc2 = jnp.sum(folds * w[1][None], axis=1)
+    p1 = x * w_ref[0:1, :]             # (BM, BN), wraps mod 2^32
+    p2 = x * w_ref[1:2, :]
+    acc1, acc2 = p1[:, :128], p2[:, :128]
+    for t in range(1, bn // 128):
+        lanes = slice(128 * t, 128 * (t + 1))
+        acc1 = acc1 + p1[:, lanes]
+        acc2 = acc2 + p2[:, lanes]
     out_ref[:, :128] += acc1
     out_ref[:, 128:] += acc2
 
@@ -147,6 +159,26 @@ def fingerprint_rows(flat_u32: jax.Array, *, use_pallas: bool | None = None,
     from repro.kernels.ref import fingerprint_ref
     return fingerprint_ref(flat_u32,
                            jnp.asarray(poly_weights(flat_u32.shape[1])))
+
+
+def fingerprint_rows_sharded(flat_u32: jax.Array, mesh, axis: str
+                             ) -> jax.Array:
+    """:func:`fingerprint_rows` on a device mesh, jit-safe: under
+    ``jax.shard_map`` over ``axis`` each device fingerprints the rows it
+    holds (Mosaic kernels cannot be partitioned automatically).
+
+    Rows are zero-padded to a multiple of the axis size — a zero row's
+    residues are sliced away — so a replicated input works as well as a
+    row-sharded one."""
+    m = flat_u32.shape[0]
+    pad = -m % mesh.shape[axis]
+    if pad:
+        flat_u32 = jnp.pad(flat_u32, ((0, pad), (0, 0)))
+    # check_vma=False: pallas_call's output carries no varying-axes type,
+    # and a P(axis) output declares no replication for the check to prove
+    per_device = jax.shard_map(fingerprint_rows, mesh=mesh, in_specs=P(axis),
+                               out_specs=P(axis), check_vma=False)
+    return per_device(flat_u32)[:m]
 
 
 @jax.jit

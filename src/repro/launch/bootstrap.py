@@ -14,6 +14,19 @@ import sys
 _FLAG = "--xla_force_host_platform_device_count"
 
 
+def refuse_reexec_after_backend() -> None:
+    """Raise if this process already created a JAX backend — read from
+    ``sys.modules``, so asking never imports jax.  Environment changes for
+    jax must re-exec before that point: a live backend may hold the
+    process's accelerator, which the re-exec'd image could not reach."""
+    xb = sys.modules.get("jax._src.xla_bridge")
+    if xb is not None and xb.backends_are_initialized():
+        raise RuntimeError(
+            "the jax environment (XLA_FLAGS / JAX_PLATFORMS) must be set "
+            "before the first jax computation; this process already created "
+            "a backend, so it cannot re-exec to apply it")
+
+
 def force_host_device_count(n: int) -> None:
     """Ensure ``XLA_FLAGS`` forces ``n`` CPU host devices, re-execing the
     current script once if the flag had to be added or changed.
@@ -28,6 +41,7 @@ def force_host_device_count(n: int) -> None:
     want = f"{_FLAG}={n}"
     if want in flags:
         return
+    refuse_reexec_after_backend()
     flags = [f for f in flags if not f.startswith(_FLAG + "=")]
     os.environ["XLA_FLAGS"] = " ".join(flags + [want])
     os.execv(sys.executable, [sys.executable, sys.argv[0], *sys.argv[1:]])

@@ -16,7 +16,7 @@ from repro.configs import ARCHS, SHAPES, get_config, shape_applicable  # noqa: E
 from repro.launch import sharding as shd  # noqa: E402
 from repro.launch.flops import step_cost  # noqa: E402
 from repro.launch.hlo import collective_bytes, collective_counts  # noqa: E402
-from repro.launch.mesh import make_production_mesh, use_mesh  # noqa: E402
+from repro.launch.mesh import make_production_mesh  # noqa: E402
 from repro.launch.specs import batch_pspecs, decode_inputs, train_batch_specs  # noqa: E402
 from repro.models import lm  # noqa: E402
 from repro.models.transformer import param_specs  # noqa: E402
@@ -41,7 +41,7 @@ def lower_fl_round(*, multi_pod: bool, n_clients: int = 64,
     cfg = FLTargetConfig(n_clients=n_clients, agg_method=agg_method)
     mesh = make_production_mesh(multi_pod=multi_pod)
     t0 = time.time()
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         jitted, args = build(cfg, mesh)
         lowered = jitted.lower(*args)
         t_lower = time.time() - t0
@@ -118,7 +118,7 @@ def lower_combo(arch: str, shape_name: str, *, multi_pod: bool,
     psh = _ns(mesh, pspec)
 
     t0 = time.time()
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         if shape.kind in ("train", "prefill"):
             opt = adamw(1e-4)
             oshape = jax.eval_shape(opt.init, pshape)

@@ -2,61 +2,20 @@
 
 Functions, not module-level constants — importing this module never touches
 jax device state (critical: the dry-run sets XLA_FLAGS before first jax use,
-while tests/benches must keep seeing 1 CPU device).
-
-Version compatibility: the repo targets the modern mesh API
-(``jax.sharding.AxisType``, ``jax.set_mesh``, two-argument ``AbstractMesh``)
-but must run on the installed JAX 0.4.37, which predates all three.  The
-shims below feature-detect once and degrade gracefully:
-
-  * ``_auto(n)``          → ``None`` when ``AxisType`` is absent, and every
-    ``make_mesh`` call here omits ``axis_types`` in that case (0.4.x meshes
-    are implicitly fully-auto, so behaviour is identical);
-  * ``make_abstract_mesh`` → builds ``AbstractMesh`` through whichever
-    constructor signature the installed JAX accepts (0.4.x wants a single
-    ``((name, size), ...)`` tuple and raises ``TypeError: 'int' object is
-    not iterable`` on the modern two-argument form);
-  * ``use_mesh``          → ``jax.set_mesh`` context when available, else the
-    mesh itself (``Mesh`` is a context manager in 0.4.x).
+while tests/benches must keep seeing 1 CPU device).  Every mesh here has
+Auto axis types: sharding is propagated by GSPMD from the constraints the
+engine places.  Activate one for jit/shard_map bodies with
+``jax.set_mesh(mesh)``; a device-free mesh for rule checks is
+``jax.sharding.AbstractMesh(shape, axes)``.
 """
 from __future__ import annotations
 
 import jax
-
-_AXIS_TYPE = getattr(jax.sharding, "AxisType", None)
-
-
-def _auto(n: int):
-    """``n`` Auto axis types, or ``None`` when this JAX predates AxisType."""
-    if _AXIS_TYPE is None:
-        return None
-    return (_AXIS_TYPE.Auto,) * n
+from jax.sharding import AxisType
 
 
-def compat_make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
-    """``jax.make_mesh`` with Auto axis types where the API supports them."""
-    axis_types = _auto(len(axes))
-    if axis_types is None:
-        return jax.make_mesh(shape, axes)
-    return jax.make_mesh(shape, axes, axis_types=axis_types)
-
-
-def make_abstract_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
-    """Device-free ``AbstractMesh`` across both constructor generations."""
-    from jax.sharding import AbstractMesh
-    try:
-        return AbstractMesh(shape, axes)            # modern (sizes, names)
-    except TypeError:
-        return AbstractMesh(tuple(zip(axes, shape)))  # 0.4.x shape_tuple
-
-
-def use_mesh(mesh):
-    """Context manager activating ``mesh`` for jit/shard_map bodies."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    if hasattr(jax.sharding, "use_mesh"):
-        return jax.sharding.use_mesh(mesh)
-    return mesh                                     # 0.4.x: Mesh is a CM
+def _auto_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False,
@@ -71,12 +30,12 @@ def make_production_mesh(*, multi_pod: bool = False,
     assert dm[0] * dm[1] == 256, dm
     shape = (2, *dm) if multi_pod else dm
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat_make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh(data: int = 1, model: int = 1):
     """Small mesh over however many (host) devices exist — for tests."""
-    return compat_make_mesh((data, model), ("data", "model"))
+    return _auto_mesh((data, model), ("data", "model"))
 
 
 CLIENT_AXIS = "clients"
@@ -101,7 +60,7 @@ def make_client_mesh(shards: int):
             f"{avail} exist; on CPU set XLA_FLAGS="
             f"--xla_force_host_platform_device_count={shards} before jax "
             f"initialises")
-    return compat_make_mesh((shards,), (CLIENT_AXIS,))
+    return _auto_mesh((shards,), (CLIENT_AXIS,))
 
 
 def batch_axes(mesh) -> tuple[str, ...]:

@@ -24,6 +24,8 @@ import os
 import sys
 from typing import Any, Mapping
 
+from repro.launch.bootstrap import refuse_reexec_after_backend
+
 _FORCE = "--xla_force_host_platform_device_count"
 
 
@@ -97,14 +99,18 @@ def bootstrap(spec: Any, *, reexec: bool | None = None) -> bool:
     this process, so the script re-execs once (``reexec=None`` means "only
     if jax is in ``sys.modules``"; pass False to force in-process mutation
     for tests).  Idempotent: a second call — including the re-exec'd
-    process's — finds nothing to change and falls straight through.
+    process's — finds nothing to change and falls straight through.  Once a
+    backend exists it raises instead of re-exec'ing (see
+    :func:`repro.launch.bootstrap.refuse_reexec_after_backend`).
     """
     updates = resolve_env(spec)
     if not updates:
         return False
-    os.environ.update(updates)
     if reexec is None:
         reexec = "jax" in sys.modules
+    if reexec:
+        refuse_reexec_after_backend()
+    os.environ.update(updates)
     if reexec:
         os.execv(sys.executable, [sys.executable, sys.argv[0], *sys.argv[1:]])
     return True

@@ -207,8 +207,8 @@ class ShardedParamArena(ParamArena):
     replay stays bit-identical to the unsharded engine.  The masked
     scatter-back writes only the rows each device owns.
 
-    Rows are zero-padded up to a multiple of the shard count (0.4.x
-    NamedShardings require divisible dims); padding rows sit beyond every
+    Rows are zero-padded up to a multiple of the shard count, so every
+    device holds an equal row block; padding rows sit beyond every
     real client id, are never gathered or scattered, and ``n_clients`` /
     ``as_pytree`` expose only the logical population.
 

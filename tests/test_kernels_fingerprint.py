@@ -2,15 +2,20 @@
 padding neutrality, digest sensitivity, and decision-parity of the
 fingerprint-based commitment pipeline against the legacy `hash_params`
 verification on tampered cohorts."""
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.sharding import AxisType
 
+import repro.kernels.fingerprint as fp
 from repro.blockchain import Blockchain, Transaction, TxPool, hash_params
 from repro.kernels.fingerprint import (
     cohort_digests,
     fingerprint_pallas,
+    fingerprint_rows_sharded,
     format_digest,
     poly_weights,
     stack_flatten_u32,
@@ -26,6 +31,37 @@ def test_interpret_matches_ref_oracle(m, n, rng):
     ref = np.asarray(fingerprint_ref(x, jnp.asarray(poly_weights(n))))
     pal = np.asarray(fingerprint_pallas(x, interpret=True))
     np.testing.assert_array_equal(ref, pal)
+
+
+@pytest.mark.parametrize("fill", ["all_ones", "high_bit"])
+@pytest.mark.parametrize("m,n", [(13, 1000), (3, 6570)])
+def test_interpret_matches_ref_when_accumulators_wrap(fill, m, n, rng):
+    """Every product and lane sum wraps mod 2^32: rows of 0xFFFFFFFF, and
+    random values with the high bit set, over row counts the 8-row block
+    must pad."""
+    if fill == "all_ones":
+        x = np.full((m, n), 0xFFFFFFFF, dtype=np.uint32)
+    else:
+        x = rng.integers(0, 2**31, size=(m, n), dtype=np.uint32) \
+            | np.uint32(0x80000000)
+    x = jnp.asarray(x)
+    ref = np.asarray(fingerprint_ref(x, jnp.asarray(poly_weights(n))))
+    pal = np.asarray(fingerprint_pallas(x, interpret=True))
+    np.testing.assert_array_equal(ref, pal)
+
+
+def test_sharded_rows_match_ref_on_device_mesh(rng, monkeypatch):
+    """The shard_map wrapper (kernel forced, interpret mode) over every
+    local device == the oracle; a row count no device count divides."""
+    monkeypatch.setattr(fp, "fingerprint_rows", functools.partial(
+        fp.fingerprint_rows, use_pallas=True, interpret=True))
+    mesh = jax.make_mesh((len(jax.devices()),), ("clients",),
+                         axis_types=(AxisType.Auto,))
+    x = jnp.asarray(rng.integers(0, 2**32, size=(13, 300), dtype=np.uint32))
+    got = np.asarray(jax.jit(lambda v: fingerprint_rows_sharded(
+        v, mesh, "clients"))(x))
+    ref = np.asarray(fingerprint_ref(x, jnp.asarray(poly_weights(300))))
+    np.testing.assert_array_equal(got, ref)
 
 
 def test_non_aligned_padding_is_neutral(rng):

@@ -91,6 +91,28 @@ def test_platform_bootstrap_subprocess_reexec(tmp_path):
     assert "DEVS 4" in out.stdout
 
 
+def test_platform_bootstrap_refuses_reexec_after_backend(tmp_path):
+    """Once a backend exists (it may hold the chip), bootstrap() raises
+    instead of re-exec'ing, and leaves the environment untouched."""
+    import os
+    script = tmp_path / "late.py"
+    script.write_text(
+        "import os, jax\n"
+        "jax.devices()\n"                       # backend created
+        "from repro.launch.platform import bootstrap\n"
+        "try:\n"
+        "    bootstrap({'mesh': {'shards': 4}})\n"
+        "except RuntimeError:\n"
+        "    print('REFUSED', os.environ.get('XLA_FLAGS', ''))\n")
+    env = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}
+    env["PYTHONPATH"] = os.path.abspath(
+        os.path.join(os.path.dirname(__file__), "..", "src"))
+    out = subprocess.run([sys.executable, str(script)], text=True,
+                         capture_output=True, env=env, timeout=300)
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert out.stdout.strip() == "REFUSED"
+
+
 @pytest.mark.parametrize("arch", sorted(ARCHS))
 def test_step_cost_defined_for_all_runnable_combos(arch):
     for shape_name, shape in SHAPES.items():
@@ -107,10 +129,9 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import sys
 import jax, jax.numpy as jnp
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 from repro.configs import get_config, SHAPES
 from repro.launch import sharding as shd
-from repro.launch.mesh import compat_make_mesh, use_mesh
 from repro.launch.specs import batch_pspecs, train_batch_specs
 from repro.models import lm
 from repro.models.transformer import param_specs
@@ -120,7 +141,8 @@ import dataclasses
 # reduced arch on a 4x2 mini-mesh: the same machinery as production
 cfg = dataclasses.replace(get_config("internvl2-2b").reduced(),
                           param_dtype="bfloat16")
-mesh = compat_make_mesh((4, 2), ("data", "model"))
+mesh = jax.make_mesh((4, 2), ("data", "model"),
+                     axis_types=(AxisType.Auto,) * 2)
 pshape = param_specs(cfg)
 pspec = shd.param_pspecs(cfg, pshape, mesh)
 ns = lambda t: jax.tree.map(lambda s: NamedSharding(mesh, s), t,
@@ -131,7 +153,7 @@ osh = ns(shd.opt_state_pspecs(oshape, pspec))
 shape = dataclasses.replace(SHAPES["train_4k"], seq_len=32, global_batch=8)
 batch = train_batch_specs(cfg, shape)
 bsh = ns(batch_pspecs(cfg, batch, mesh))
-with use_mesh(mesh):
+with jax.set_mesh(mesh):
     step = lm.make_train_step(cfg, opt)
     compiled = jax.jit(step, in_shardings=(ns(pspec), osh, bsh),
                        out_shardings=(NamedSharding(mesh, P()), ns(pspec), osh)

@@ -46,18 +46,18 @@ _EP_SCRIPT = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import jax, jax.numpy as jnp, numpy as np
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 from repro.models.moe import moe_apply, moe_init, moe_capacity
 from repro.models.moe_sharded import moe_apply_shard_map
 
-from repro.launch.mesh import compat_make_mesh, use_mesh
-mesh = compat_make_mesh((2, 2), ("data", "model"))
+mesh = jax.make_mesh((2, 2), ("data", "model"),
+                     axis_types=(AxisType.Auto,) * 2)
 E, D, F, T, k = 4, 16, 32, 64, 2
 p = moe_init(jax.random.PRNGKey(0), "swiglu", D, F, E, jnp.float32)
 x = jax.random.normal(jax.random.PRNGKey(1), (8, 8, D))
 cap = moe_capacity(T, k, E, multiple=8)
 
-with use_mesh(mesh):
+with jax.set_mesh(mesh):
     y_ref, aux_ref = jax.jit(
         lambda p, x: moe_apply("swiglu", p, x, top_k=k, capacity=cap))(p, x)
     # EP path: per-shard capacity = cap // 2 per local dispatch -> give the

@@ -5,19 +5,18 @@ environment while still exercising the exact production mesh shapes.
 """
 import jax
 import pytest
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AbstractMesh, PartitionSpec as P
 
 from repro.configs import ARCHS
 from repro.launch import sharding as shd
-from repro.launch.mesh import make_abstract_mesh
 from repro.models.decode import init_cache
 from repro.models.transformer import param_specs
 
 
 def _mesh(multi=False):
     if multi:
-        return make_abstract_mesh((2, 16, 16), ("pod", "data", "model"))
-    return make_abstract_mesh((16, 16), ("data", "model"))
+        return AbstractMesh((2, 16, 16), ("pod", "data", "model"))
+    return AbstractMesh((16, 16), ("data", "model"))
 
 
 def _check_divisibility(shapes, specs, mesh):
