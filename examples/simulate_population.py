@@ -25,9 +25,11 @@ Finishes in well under 2 minutes on CPU.  Scenario knobs:
   --mesh-shards N                           row-shard the parameter arena
                                             over an N-device client mesh
                                             (CPU devices self-forced)
-  --trace t.jsonl [--chrome-trace t.json]   flight-recorder trace (repro.obs):
+  --trace t.jsonl [--profile-dir D]        flight-recorder trace (repro.obs):
                                             per-phase spans + metrics, digest
-                                            stamped into the manifest
+                                            stamped into the manifest; D gets
+                                            a jax.profiler trace with the
+                                            spans beside the device ops
   --checkpoint-interval N --checkpoint-dir D   snapshot the complete state
                                             every N rounds/flushes (keep-last
                                             --keep-last); --resume continues
@@ -86,7 +88,7 @@ def build_spec(args) -> api.ExperimentSpec:
         eval=api.EvalSpec(every=5),
         mesh=api.MeshSpec(shards=args.mesh_shards),
         obs=api.ObsSpec(enabled=True, trace_path=args.trace,
-                        chrome_path=args.chrome_trace, console=True)
+                        profile_dir=args.profile_dir, console=True)
         if args.trace else api.ObsSpec(),
         checkpoint=api.CheckpointSpec(interval=args.checkpoint_interval,
                                       dir=args.checkpoint_dir,
@@ -137,8 +139,9 @@ def main():
                     help="record a flight-recorder trace (repro.obs): JSONL "
                          "to PATH, per-phase console table, trace sha256 "
                          "stamped into the manifest")
-    ap.add_argument("--chrome-trace", default=None, metavar="PATH",
-                    help="with --trace: also export a Chrome/Perfetto trace")
+    ap.add_argument("--profile-dir", default=None, metavar="DIR",
+                    help="with --trace: also write a jax.profiler trace to "
+                         "DIR (open in Perfetto or TensorBoard)")
     ap.add_argument("--checkpoint-interval", type=int, default=0,
                     help="snapshot the complete experiment state every N "
                          "rounds/flushes (0 = off)")

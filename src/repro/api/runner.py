@@ -15,7 +15,7 @@ from dataclasses import dataclass, field
 from typing import Any
 
 from repro.api.spec import ExperimentSpec
-from repro.obs import console_summary, write_chrome_trace, write_jsonl
+from repro.obs import console_summary, write_jsonl
 from repro.sim import ClientPopulation, SimReport, SimulatedFederation
 
 
@@ -148,9 +148,6 @@ def _emit_trace(spec: ExperimentSpec, sim: SimulatedFederation,
     manifest["trace_path"] = spec.obs.trace_path
     manifest["trace_digest"] = digest
     manifest["timing"] = obs.timing_summary()
-    if spec.obs.chrome_path is not None:
-        write_chrome_trace(spec.obs.chrome_path, obs.records)
-        manifest["chrome_trace_path"] = spec.obs.chrome_path
     if spec.obs.console:
         print(console_summary(
             obs.metrics, title=f"trace {spec.train.strategy}/"

@@ -216,11 +216,16 @@ def paa_round(
     kmeans_iters: int = 25,
     agg_method: str = "two_step",
 ) -> PAAResult:
-    """One full PAA aggregation (paper steps 3–5 of Fig. 1)."""
-    protos = client_prototypes(embed_fn, stacked_params, probe_x)      # (m, D)
-    corr = pearson_matrix(protos)                                      # (m, m)
-    labels = spectral_cluster(pearson_affinity(corr), n_clusters, kmeans_iters)
-    new_params = cluster_mean_params(stacked_params, labels, n_clusters, weights,
-                                     method=agg_method)
+    """One full PAA aggregation (paper steps 3–5 of Fig. 1).  The clustering
+    runs under the ``paa`` named scope and the means under
+    ``cluster_means``, so a profile can tell their device time apart."""
+    with jax.named_scope("paa"):
+        protos = client_prototypes(embed_fn, stacked_params, probe_x)  # (m, D)
+        corr = pearson_matrix(protos)                                  # (m, m)
+        labels = spectral_cluster(pearson_affinity(corr), n_clusters,
+                                  kmeans_iters)
+    with jax.named_scope("cluster_means"):
+        new_params = cluster_mean_params(stacked_params, labels, n_clusters,
+                                         weights, method=agg_method)
     sizes = cluster_sizes(labels, n_clusters)
     return PAAResult(new_params, labels, corr, protos, sizes)

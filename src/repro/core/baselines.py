@@ -135,13 +135,15 @@ def _tree_masked_mean(stacked_params: Pytree, arrived_w: jax.Array,
     zero-weight padding slots beyond ``k``.
     """
     w = arrived_w.astype(jnp.float32)
-    denom = jnp.maximum(tree_sum(w), 1.0)
+    with jax.named_scope("cluster_means"):
+        denom = jnp.maximum(tree_sum(w), 1.0)
 
-    def leaf(x):
-        mean = masked_tree_sum(x.astype(jnp.float32), w) / denom
-        return jnp.broadcast_to(mean[None], (k,) + mean.shape).astype(x.dtype)
+        def leaf(x):
+            mean = masked_tree_sum(x.astype(jnp.float32), w) / denom
+            return jnp.broadcast_to(mean[None],
+                                    (k,) + mean.shape).astype(x.dtype)
 
-    return jax.tree.map(leaf, stacked_params)
+        return jax.tree.map(leaf, stacked_params)
 
 
 def compose_cohort(partial_fn: Callable, combine_fn: Callable) -> Callable:
@@ -365,7 +367,8 @@ def make_bfln(model: ModelBundle, probe_x: jax.Array, n_clusters: int,
         # per-slot prototypes (m, D): the ONLY cross-slot input the combine
         # needs — each device embeds the shared probe batch through its own
         # cohort slice, and only this small matrix gets replicated
-        return client_prototypes(model.embed_fn, stacked_params, probe_x)
+        with jax.named_scope("paa"):
+            return client_prototypes(model.embed_fn, stacked_params, probe_x)
 
     def cohort_combine(stacked_params, protos, arrived_w, k):
         # PAA with the arrival mask as aggregation weights.  Pearson +
@@ -375,16 +378,19 @@ def make_bfln(model: ModelBundle, probe_x: jax.Array, n_clusters: int,
         # m >= k slots through the fixed-order tree segment sums — padding
         # slots carry zero weight, so their garbage params and arbitrary
         # labels contribute exactly +0.0
-        corr = pearson_matrix(protos[:k])
-        labels = spectral_cluster(pearson_affinity(corr), n_clusters,
-                                  kmeans_iters)
+        with jax.named_scope("paa"):
+            corr = pearson_matrix(protos[:k])
+            labels = spectral_cluster(pearson_affinity(corr), n_clusters,
+                                      kmeans_iters)
         m = protos.shape[0]
-        labels_m = labels if m == k else jnp.concatenate(
-            [labels, jnp.zeros((m - k,), labels.dtype)])
-        new_params = tree_cluster_mean_params(stacked_params, labels_m,
-                                              n_clusters, weights=arrived_w)
-        if m != k:
-            new_params = jax.tree.map(lambda x: x[:k], new_params)
+        with jax.named_scope("cluster_means"):
+            labels_m = labels if m == k else jnp.concatenate(
+                [labels, jnp.zeros((m - k,), labels.dtype)])
+            new_params = tree_cluster_mean_params(stacked_params, labels_m,
+                                                  n_clusters,
+                                                  weights=arrived_w)
+            if m != k:
+                new_params = jax.tree.map(lambda x: x[:k], new_params)
         return CohortAggOut(new_params, labels, corr)
 
     return Strategy("bfln", round_extras, local_loss, aggregate,

@@ -72,7 +72,6 @@ from repro.kernels.fingerprint import (
     fingerprint_rows_sharded,
     format_digest,
 )
-from repro.obs import NULL_RECORDER
 from repro.runtime.arena import ArenaLayout, bitcast_u32
 
 Pytree = Any
@@ -113,7 +112,6 @@ class RoundEngine:
         stacked_apply_fn: Callable | None = None,
         sharding=None,                  # client-axis NamedSharding (mesh mode)
         cohort_mode: str = "sharded",   # mesh mode: "sharded" | "replicated"
-        obs=NULL_RECORDER,              # repro.obs flight recorder
     ):
         if strategy.aggregate_cohort is None:
             raise ValueError(
@@ -170,10 +168,12 @@ class RoundEngine:
             """(m, N) fp32 rows -> (m, 2) residues.  On a mesh the kernel
             runs per device under shard_map — Mosaic kernels cannot be
             partitioned automatically."""
-            if sharding is None:
-                return fingerprint_rows(bitcast_u32(rows))
-            return fingerprint_rows_sharded(bitcast_u32(rows), sharding.mesh,
-                                            sharding.mesh.axis_names[0])
+            with jax.named_scope("fingerprint"):
+                if sharding is None:
+                    return fingerprint_rows(bitcast_u32(rows))
+                return fingerprint_rows_sharded(bitcast_u32(rows),
+                                                sharding.mesh,
+                                                sharding.mesh.axis_names[0])
 
         def _pad0(x, pad):
             """Append ``pad`` zero slots along the leading (cohort) axis."""
@@ -221,16 +221,18 @@ class RoundEngine:
             k = cohort_idx.shape[0]
             pad = _cohort_pad(k)
             if sharded_cohort:
-                # padding slots gather row 0 (any valid row — their outputs
-                # are sliced away and their arrival weight is zero)
-                idx_p = jnp.concatenate(
-                    [cohort_idx, jnp.zeros((pad,), cohort_idx.dtype)]) \
-                    if pad else cohort_idx
-                # shard-aware gather: each device receives only its cohort
-                # slice — no replicated (k, N) block materialises
-                rows = _csh(arena[idx_p])
-                cx_p, cy_p = _csh(_pad0(cx, pad)), _csh(_pad0(cy, pad))
-                arrived_p = _pad0(arrived, pad)
+                with jax.named_scope("gather"):
+                    # padding slots gather row 0 (any valid row — their
+                    # outputs are sliced away and their arrival weight is
+                    # zero)
+                    idx_p = jnp.concatenate(
+                        [cohort_idx, jnp.zeros((pad,), cohort_idx.dtype)]) \
+                        if pad else cohort_idx
+                    # shard-aware gather: each device receives only its
+                    # cohort slice — no replicated (k, N) block materialises
+                    rows = _csh(arena[idx_p])
+                    cx_p, cy_p = _csh(_pad0(cx, pad)), _csh(_pad0(cy, pad))
+                    arrived_p = _pad0(arrived, pad)
                 # server payload on the replicated REAL slots with the exact
                 # single-device op sequence: round_extras may reduce over
                 # the cohort (fedprox anchor, fedproto/fedhkd global
@@ -239,10 +241,13 @@ class RoundEngine:
                 # lets GSPMD back-propagate the training consumer's cohort
                 # sharding through the broadcast into the reduction,
                 # rewriting it into partial sums + all-reduce (ULP flips)
-                rows_real = _rep(rows[:k])
-                extras = _pad_extras(jax.tree.map(_rep, strategy.round_extras(
-                    layout.unflatten(rows_real), _rep(cx), _rep(cy))), pad)
-                res = _train(layout.unflatten(rows), cx_p, cy_p, extras)
+                with jax.named_scope("local_train"):
+                    rows_real = _rep(rows[:k])
+                    extras = _pad_extras(jax.tree.map(
+                        _rep, strategy.round_extras(
+                            layout.unflatten(rows_real), _rep(cx),
+                            _rep(cy))), pad)
+                    res = _train(layout.unflatten(rows), cx_p, cy_p, extras)
                 # shard-local per-slot partial (BFLN: prototypes); only this
                 # small matrix replicates into the deterministic combine —
                 # whose cohort-axis reductions are fixed-order trees, so the
@@ -283,9 +288,12 @@ class RoundEngine:
                 mean_loss = jnp.mean(res.mean_loss[:k])
                 prev_rows = rows[:k]
             else:
-                rows = _rep(arena[cohort_idx])
-                extras = strategy.round_extras(layout.unflatten(rows), cx, cy)
-                res = _train(layout.unflatten(rows), cx, cy, extras)
+                with jax.named_scope("gather"):
+                    rows = _rep(arena[cohort_idx])
+                with jax.named_scope("local_train"):
+                    extras = strategy.round_extras(layout.unflatten(rows),
+                                                   cx, cy)
+                    res = _train(layout.unflatten(rows), cx, cy, extras)
                 # aggregation over ALL cohort slots (stragglers burn local
                 # compute too); only the aggregation weights honour the
                 # arrival mask
@@ -298,14 +306,15 @@ class RoundEngine:
                 residues = _fingerprint(_rep(local_rows))
                 mean_loss = jnp.mean(res.mean_loss)
                 prev_rows = rows
-            new_rows = layout.flatten(agg.stacked_params)
             # masked scatter-back: arrived slots adopt their aggregated
             # params, everyone else keeps their previous personalized row.
             # Only the k REAL indices are written (a padded scatter would
             # race its duplicate row-0 slots), and each device lands only
             # the rows it owns — the donated arena stays row-sharded.
-            upd = jnp.where(arrived[:, None] > 0, new_rows, prev_rows)
-            arena = _shd(arena.at[cohort_idx].set(upd))
+            with jax.named_scope("scatter_back"):
+                new_rows = layout.flatten(agg.stacked_params)
+                upd = jnp.where(arrived[:, None] > 0, new_rows, prev_rows)
+                arena = _shd(arena.at[cohort_idx].set(upd))
             return arena, SyncRoundOut(agg.labels, agg.corr, residues,
                                        mean_loss, upd)
 
@@ -319,24 +328,28 @@ class RoundEngine:
             k = base_rows.shape[0]
             pad = _cohort_pad(k)
             if sharded_cohort:
-                rows = _csh(_pad0(base_rows, pad))
-                cx_p, cy_p = _csh(_pad0(cx, pad)), _csh(_pad0(cy, pad))
+                with jax.named_scope("gather"):
+                    rows = _csh(_pad0(base_rows, pad))
+                    cx_p, cy_p = _csh(_pad0(cx, pad)), _csh(_pad0(cy, pad))
                 # extras replicated end-to-end, as in the sync step: the
                 # flush-batch rows feed both the sharded training gather and
                 # the cohort-reducing server payload, and the latter must
                 # keep the single-device op sequence
-                extras = _pad_extras(jax.tree.map(_rep, strategy.round_extras(
-                    layout.unflatten(_rep(base_rows)), _rep(cx), _rep(cy))),
-                    pad)
-                res = _train(layout.unflatten(rows), cx_p, cy_p, extras)
+                with jax.named_scope("local_train"):
+                    extras = _pad_extras(jax.tree.map(
+                        _rep, strategy.round_extras(
+                            layout.unflatten(_rep(base_rows)), _rep(cx),
+                            _rep(cy))), pad)
+                    res = _train(layout.unflatten(rows), cx_p, cy_p, extras)
                 local_rows_p = layout.flatten(res.params)
                 residues = _fingerprint(local_rows_p)[:k]
                 local_rows = _rep(local_rows_p[:k])
                 mean_loss = jnp.mean(res.mean_loss[:k])
                 return local_rows, residues, mean_loss
-            extras = strategy.round_extras(layout.unflatten(base_rows),
-                                           cx, cy)
-            res = _train(layout.unflatten(base_rows), cx, cy, extras)
+            with jax.named_scope("local_train"):
+                extras = strategy.round_extras(layout.unflatten(base_rows),
+                                               cx, cy)
+                res = _train(layout.unflatten(base_rows), cx, cy, extras)
             local_rows = layout.flatten(res.params)
             residues = _fingerprint(_rep(local_rows))
             return local_rows, residues, jnp.mean(res.mean_loss)
@@ -382,14 +395,12 @@ class RoundEngine:
             rows = _rep(arena[ids])       # replicate only the sampled rows
             return jnp.mean(_client_accs(layout.unflatten(rows), ex, ey))
 
-        self.obs = obs
         self.sync_step = jax.jit(_sync_step, donate_argnums=(0,))
         self.async_step = jax.jit(_async_step)
         self.eval_cohort = jax.jit(_eval_cohort)
         self.eval_global = jax.jit(_eval_global)
         self.eval_population = jax.jit(_eval_population)
-        # raw jitted fns — cache_sizes() must read _cache_size() on these
-        # even when the public attributes are wrapped with call counters
+        # the jitted entries by name, for cache_sizes() and lower_entry()
         self._entries = {
             "sync_step": self.sync_step,
             "async_step": self.async_step,
@@ -397,16 +408,6 @@ class RoundEngine:
             "eval_global": self.eval_global,
             "eval_population": self.eval_population,
         }
-        if obs.enabled:
-            # per-entry call counters (metrics only — timing lives in the
-            # caller's spans, which know the round index)
-            def _counted(name, fn):
-                def wrapper(*a, **kw):
-                    obs.inc(f"engine.calls.{name}")
-                    return fn(*a, **kw)
-                return wrapper
-            for name, fn in self._entries.items():
-                setattr(self, name, _counted(name, fn))
 
     # ------------------------------------------------------------------ #
 
@@ -425,10 +426,9 @@ class RoundEngine:
         return list(self._entries)
 
     def lower_entry(self, name: str, *args):
-        """Lower (without executing) the RAW jitted entry ``name`` on
-        ``args`` — the hook the compiled-artifact audit uses to inspect the
-        exact programs the driver runs.  Bypasses the obs call-count
-        wrappers so lowering never shows up as an engine call."""
+        """Lower (without executing) the jitted entry ``name`` on ``args`` —
+        the hook the compiled-artifact audit uses to inspect the exact
+        programs the driver runs."""
         return self._entries[name].lower(*args)
 
     def format_digests(self, residues) -> list[str]:

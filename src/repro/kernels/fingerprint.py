@@ -136,6 +136,7 @@ def fingerprint_pallas(flat_u32: jax.Array, *, block_m: int = 8,
         out_specs=pl.BlockSpec((block_m, 256), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((mp, 256), jnp.uint32),
         interpret=interpret,
+        name="fingerprint",
     )(x, w)
     # exact cross-lane fold (modular addition commutes)
     return jnp.stack([jnp.sum(lanes[:m, :128], axis=1, dtype=jnp.uint32),

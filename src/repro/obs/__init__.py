@@ -12,7 +12,9 @@ phase (sample, gather, donated step, digests, chain, eval, async flush),
 explicit compile events from `RoundEngine.cache_sizes()` deltas, and a
 metrics registry of per-round counters/gauges with streaming p50/p99
 summaries.  Sinks: a schema-validated JSONL trace (digest stamped into the
-run manifest), a console summary table, and a Chrome/Perfetto export.
+run manifest) and a console summary table; every span is also a
+``jax.profiler`` annotation, so a profile (``ObsSpec.profile_dir``) shows
+the spans beside the device ops.
 
 Hard invariant: tracing on vs. off leaves event logs, block hashes, ledger
 balances and final accuracy bit-identical — observability may time and
@@ -41,7 +43,6 @@ from repro.obs.schema import (  # noqa: F401
 from repro.obs.sinks import (  # noqa: F401
     console_summary,
     file_sha256,
-    write_chrome_trace,
     write_jsonl,
 )
 from repro.obs.spec import ObsSpec  # noqa: F401

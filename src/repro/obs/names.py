@@ -16,8 +16,9 @@ cross-checks three ways and fails CI on drift:
        resolve against this registry.
 
 Dynamic name families (f-strings with a literal prefix, e.g.
-``f"engine.calls.{name}"``) are registered as prefixes in
-:data:`DYNAMIC_PREFIXES`; the schema doc spells them ``engine.calls.<entry>``.
+``f"family.{name}"``) are registered as prefixes in
+:data:`DYNAMIC_PREFIXES`; the schema doc spells them ``family.<name>``.
+None is registered today.
 
 This module is imported by the static analyzer, which must run without jax —
 keep it dependency-free.
@@ -27,19 +28,21 @@ from __future__ import annotations
 # --- spans: timed phases (recorder.span) --------------------------------- #
 SPAN_NAMES = frozenset({
     # sync round phases (cat "round")
-    "round.total", "round.sample", "round.wait", "round.gather",
-    "round.step", "round.digests", "round.chain", "round.scatter",
-    "round.eval", "round.retry",
-    # async FedBuff flush phases (cat "flush")
-    "flush.total", "flush.gather", "flush.step", "flush.chain",
-    "flush.merge", "flush.eval",
+    "round.total", "round.sample", "round.schedule", "round.wait",
+    "round.gather", "round.step", "round.digests", "round.chain",
+    "round.scatter", "round.record", "round.eval", "round.retry",
+    # async FedBuff flush phases and the event loop (cat "flush")
+    "flush.total", "flush.gather", "flush.prepare", "flush.step",
+    "flush.chain", "flush.merge", "flush.record", "flush.eval",
+    "async.dispatch",
     # blockchain phases (cat "chain")
     "chain.pack", "chain.validate", "chain.verify", "chain.digests",
     "chain.commit", "chain.consensus", "chain.rewards",
     # checkpoint / run lifecycle
     "ckpt.save", "ckpt.restore", "run.final_eval",
     # serving tier (cat "serve", repro.serve)
-    "serve.snapshot", "serve.verify", "serve.batch", "serve.flush",
+    "serve.snapshot", "serve.verify", "serve.pack", "serve.batch",
+    "serve.readback", "serve.flush",
 })
 
 # --- events: point-in-time markers (recorder.event) ----------------------- #
@@ -75,7 +78,7 @@ SERIES_NAMES = frozenset({
 
 # Dynamic families: a recorder call may build its name with an f-string as
 # long as the literal prefix is registered here (schema doc: `<...>` suffix).
-DYNAMIC_PREFIXES = ("engine.calls.",)
+DYNAMIC_PREFIXES: tuple[str, ...] = ()
 
 # recorder method -> the name set it is checked against
 METHOD_NAME_SETS = {

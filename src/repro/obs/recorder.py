@@ -17,7 +17,11 @@ Span records carry two clocks: host wall time (``ts_us``/``dur_us``,
 microseconds since trace start) and the simulator's *virtual* clock (``vt``
 at span close, plus a ``vt_dur`` attr when virtual time advanced inside the
 span) — so a trace shows both where a round's milliseconds go and where its
-simulated seconds go.
+simulated seconds go.  Each span also has a run-unique ``id`` and the
+``parent`` id of the innermost span open when it began (``None`` at the
+top), and opens a ``jax.profiler.TraceAnnotation`` of the same name: under a
+profiler trace (``ObsSpec.profile_dir``) the spans sit on the profiler's
+own clock beside the device ops, nested as in the records.
 
 Compile events are sourced from ``RoundEngine.cache_sizes()`` deltas
 (:meth:`FlightRecorder.compile_delta`): the engine's jit caches are the
@@ -38,7 +42,8 @@ class _Span:
     """A timed phase.  ``with rec.span("round.step", round=r) as sp: ...``;
     ``sp.set(k=v)`` attaches attributes before close."""
 
-    __slots__ = ("_rec", "name", "cat", "round", "attrs", "_t0", "_vt0")
+    __slots__ = ("_rec", "name", "cat", "round", "attrs", "id", "parent",
+                 "_annot", "_t0", "_vt0")
 
     def __init__(self, rec: "FlightRecorder", name: str, cat: str,
                  round_idx: int | None, attrs: dict):
@@ -52,19 +57,30 @@ class _Span:
         self.attrs.update(attrs)
 
     def __enter__(self) -> "_Span":
-        self._vt0 = self._rec._vt()
+        rec = self._rec
+        self.id = rec._next_id
+        rec._next_id += 1
+        self.parent = rec._open[-1] if rec._open else None
+        rec._open.append(self.id)
+        ids = {"id": self.id} if self.parent is None else \
+            {"id": self.id, "parent": self.parent}
+        self._annot = rec._annotation(self.name, **ids)
+        self._annot.__enter__()
+        self._vt0 = rec._vt()
         self._t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc) -> bool:
         t1 = time.perf_counter_ns()
+        self._annot.__exit__(None, None, None)
         rec = self._rec
+        rec._open.pop()
         vt1 = rec._vt()
         if self._vt0 is not None and vt1 is not None and vt1 != self._vt0:
             self.attrs["vt_dur"] = vt1 - self._vt0
         dur_us = (t1 - self._t0) / 1e3
         record = {"kind": "span", "name": self.name, "cat": self.cat,
-                  "round": self.round,
+                  "round": self.round, "id": self.id, "parent": self.parent,
                   "ts_us": round((self._t0 - rec._t0) / 1e3, 3),
                   "dur_us": round(dur_us, 3), "vt": vt1}
         if self.attrs:
@@ -144,6 +160,11 @@ class FlightRecorder:
         self._clock = clock
         self._t0 = time.perf_counter_ns()
         self._cache_prev: dict[str, int] = {}
+        self._next_id = 0
+        self._open: list[int] = []      # ids of the spans open, innermost last
+        # imported here: the package stays importable without jax
+        from jax.profiler import TraceAnnotation
+        self._annotation = TraceAnnotation
 
     # -------------------------------------------------------------- #
     # clock plumbing

@@ -4,9 +4,11 @@ Kinds (the ``kind`` field picks the shape; unknown kinds are rejected):
 
     meta     first line of every trace; ``schema`` carries the version and
              the rest mirrors the run manifest (config digest, strategy, …)
-    span     a timed phase: ``name``, ``cat``, nullable ``round``, wall-time
-             ``ts_us``/``dur_us`` (µs since trace start / duration), nullable
-             virtual-clock ``vt``, optional ``attrs`` object
+    span     a timed phase: ``name``, ``cat``, nullable ``round``, run-unique
+             ``id``, nullable ``parent`` (the id of the innermost span open
+             when it began), wall-time ``ts_us``/``dur_us`` (µs since trace
+             start / duration), nullable virtual-clock ``vt``, optional
+             ``attrs`` object
     event    a point-in-time marker (e.g. ``compile``): ``name``, nullable
              ``round``, ``ts_us``, optional ``attrs``
     point    one per-round metric observation: ``name``, ``value``,
@@ -24,7 +26,7 @@ from __future__ import annotations
 
 from typing import Any, Mapping
 
-SCHEMA_VERSION = 1
+SCHEMA_VERSION = 2
 
 _NUM = (int, float)
 
@@ -63,6 +65,12 @@ def validate_record(rec: Mapping) -> str:
         _require(rec, "name", str)
         _require(rec, "cat", str)
         _require(rec, "round", int, nullable=True)
+        span_id = _require(rec, "id", int)
+        if span_id < 0:
+            raise ValueError(f"id must be >= 0: {rec}")
+        parent = _require(rec, "parent", int, nullable=True)
+        if parent is not None and not 0 <= parent < span_id:
+            raise ValueError(f"parent must name an earlier span: {rec}")
         if _require(rec, "ts_us", _NUM) < 0:
             raise ValueError(f"ts_us must be >= 0: {rec}")
         if _require(rec, "dur_us", _NUM) < 0:
@@ -95,18 +103,32 @@ def validate_record(rec: Mapping) -> str:
 
 def validate_trace_lines(lines) -> dict[str, int]:
     """Validate an iterable of JSONL lines; returns per-kind counts.  The
-    first record must be the ``meta`` header."""
+    first record must be the ``meta`` header, span ids must be unique, and
+    every span's ``parent`` must be the id of a span in the trace (a span is
+    written when it closes, so a child's line comes before its parent's)."""
     import json
     counts: dict[str, int] = {}
+    ids: set[int] = set()
+    parents: list[dict] = []
     for i, line in enumerate(lines):
         line = line.strip()
         if not line:
             raise ValueError(f"blank line {i} in trace")
-        kind = validate_record(json.loads(line))
+        rec = json.loads(line)
+        kind = validate_record(rec)
         if i == 0 and kind != "meta":
             raise ValueError(f"first trace record must be meta, got {kind!r}")
         counts[kind] = counts.get(kind, 0) + 1
+        if kind == "span":
+            if rec["id"] in ids:
+                raise ValueError(f"duplicate span id: {rec}")
+            ids.add(rec["id"])
+            if rec["parent"] is not None:
+                parents.append(rec)
     if counts.get("meta", 0) != 1:
         raise ValueError(f"trace must contain exactly one meta record, "
                          f"got {counts.get('meta', 0)}")
+    for rec in parents:
+        if rec["parent"] not in ids:
+            raise ValueError(f"parent names no span in the trace: {rec}")
     return counts

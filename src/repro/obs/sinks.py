@@ -1,4 +1,4 @@
-"""Trace sinks: JSONL file (digest-stamped), console summary, Chrome trace.
+"""Trace sinks: JSONL file (digest-stamped) and console summary.
 
 The JSONL sink is the canonical artifact: every record the flight recorder
 captured, one JSON object per line (schema: `repro.obs.schema`), written
@@ -6,12 +6,9 @@ with sorted keys and compact separators so the file — and therefore its
 sha256, which `repro.api.run` stamps into the manifest — is deterministic
 given the same records.
 
-The Chrome export rewrites the same spans into the Trace Event Format
-(``chrome://tracing`` / https://ui.perfetto.dev): spans become complete
-("X") events on one track per category, compile events become instant
-markers.  For device-level detail, ``ObsSpec.profile_dir`` additionally
-wraps the run in ``jax.profiler.trace`` — the recorder's spans then line up
-with XLA's own timeline in the same Perfetto UI.
+For a timeline, ``ObsSpec.profile_dir`` wraps the run in
+``jax.profiler.trace``: every recorder span is also a profiler annotation,
+so the profile shows the spans beside XLA's device ops in one Perfetto view.
 """
 from __future__ import annotations
 
@@ -61,36 +58,6 @@ def file_sha256(path: str) -> str:
         for chunk in iter(lambda: f.read(1 << 20), b""):
             h.update(chunk)
     return h.hexdigest()
-
-
-def write_chrome_trace(path: str, records: list[dict]) -> int:
-    """Export spans/events as a Chrome Trace Event Format file; returns the
-    number of trace events written.  One ``tid`` per span category keeps
-    driver phases, chain internals, and ledger flows on separate tracks."""
-    events: list[dict] = []
-    tids: dict[str, int] = {}
-    for rec in records:
-        kind = rec.get("kind")
-        if kind == "span":
-            tid = tids.setdefault(rec["cat"], len(tids) + 1)
-            args = dict(rec.get("attrs", {}))
-            if rec.get("round") is not None:
-                args["round"] = rec["round"]
-            if rec.get("vt") is not None:
-                args["vt"] = rec["vt"]
-            events.append({"name": rec["name"], "cat": rec["cat"], "ph": "X",
-                           "ts": rec["ts_us"], "dur": rec["dur_us"],
-                           "pid": 1, "tid": tid, "args": args})
-        elif kind == "event":
-            args = dict(rec.get("attrs", {}))
-            if rec.get("round") is not None:
-                args["round"] = rec["round"]
-            events.append({"name": rec["name"], "cat": "event", "ph": "i",
-                           "s": "g", "ts": rec["ts_us"], "pid": 1, "tid": 0,
-                           "args": args})
-    with open(path, "w") as f:
-        json.dump({"traceEvents": events, "displayTimeUnit": "ms"}, f)
-    return len(events)
 
 
 def console_summary(metrics: MetricsRegistry, *, title: str = "trace") -> str:

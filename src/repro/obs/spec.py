@@ -3,8 +3,8 @@
 Lives in its own jax-free module so :mod:`repro.api.spec` (and schema
 tooling) can import it without pulling in the runtime.  The spec is the only
 knob surface: everything the flight recorder does — whether it records at
-all, where the JSONL trace lands, whether a Chrome/Perfetto export or a
-console summary is produced — is declared here and travels with the
+all, where the JSONL trace lands, whether a profiler trace or a console
+summary is produced — is declared here and travels with the
 experiment's JSON round trip.
 
 Observability is *out of band* by contract: it may time and count but never
@@ -33,12 +33,12 @@ class ObsSpec:
     """
     enabled: bool = False
     trace_path: str = "trace.jsonl"   # JSONL sink; sha256 lands in the manifest
-    chrome_path: str | None = None    # optional Chrome/Perfetto trace export
     console: bool = False             # print the per-phase summary table
     block_until_ready: bool = True    # sync device inside timed spans so a
                                       # span's wall time covers the device work
                                       # it launched (timing only — never values)
-    profile_dir: str | None = None    # wrap the run in jax.profiler.trace()
+    profile_dir: str | None = None    # wrap the run in jax.profiler.trace():
+                                      # spans beside the device ops, one view
     sample_cap: int = 2048            # streaming-summary reservoir size
 
     def __post_init__(self):
@@ -46,7 +46,6 @@ class ObsSpec:
                "trace_path must be a non-empty string")
         _check(self.sample_cap >= 8,
                f"sample_cap must be >= 8, got {self.sample_cap}")
-        for name in ("chrome_path", "profile_dir"):
-            v = getattr(self, name)
-            _check(v is None or (isinstance(v, str) and v != ""),
-                   f"{name} must be None or a non-empty string, got {v!r}")
+        v = self.profile_dir
+        _check(v is None or (isinstance(v, str) and v != ""),
+               f"profile_dir must be None or a non-empty string, got {v!r}")

@@ -158,14 +158,22 @@ class ServeFrontend:
         batch, self._pending = self._pending[:n], self._pending[n:]
         bucket = self._bucket_for(len(batch))
         mcfg = self.engine.bank.mcfg
-        with self.obs.span("serve.flush", cat="serve") as sp:
-            x = np.zeros((bucket, mcfg.in_dim), dtype=np.float32)
-            cids = np.zeros((bucket,), dtype=np.int32)
-            for i, r in enumerate(batch):
-                x[i] = r.x
-                cids[i] = r.cluster_id
-            logits = np.asarray(jax.device_get(
-                self.engine.forward(x, cids)))
+        obs = self.obs
+        with obs.span("serve.flush", cat="serve") as sp:
+            if obs.enabled:
+                # each request's queue wait, arrival to this flush's start
+                t = self._now()
+                waits = [(t - r.t_arrival) * 1e6 for r in batch]
+                sp.set(wait_sum_us=sum(waits), wait_max_us=max(waits))
+            with obs.span("serve.pack", cat="serve"):
+                x = np.zeros((bucket, mcfg.in_dim), dtype=np.float32)
+                cids = np.zeros((bucket,), dtype=np.int32)
+                for i, r in enumerate(batch):
+                    x[i] = r.x
+                    cids[i] = r.cluster_id
+            out = self.engine.forward(x, cids)
+            with obs.span("serve.readback", cat="serve"):
+                logits = np.asarray(jax.device_get(out))
             sp.set(n=len(batch), bucket=bucket, reason=reason)
         now = self._now()
         for i, r in enumerate(batch):

@@ -343,8 +343,7 @@ class SimulatedFederation:
                 n_clusters=n_clusters, local_epochs=epochs,
                 stacked_apply_fn=functools.partial(clf.apply_stacked, mcfg),
                 sharding=getattr(self.arena, "sharding", None),
-                cohort_mode=config.mesh_cohort,
-                obs=self.obs)
+                cohort_mode=config.mesh_cohort)
             if self.obs.enabled:
                 self.obs.set_gauge("arena.bytes", int(self.arena.data.nbytes))
                 per_dev = getattr(self.arena, "per_device_bytes", None)
@@ -493,21 +492,22 @@ class SimulatedFederation:
             online = pop.online_clients(rng)
             cohort = self.sampler(rng, online, k, self._sampler_state())
             sp.set(online=len(online), k=len(cohort))
-        self.queue.push(t0 + cfg.deadline, ev.BLOCK_SLOT, round_idx=r)
 
         dropouts: set[int] = set()        # classified at schedule time — a
-        for gid in cohort:                # dropout past the deadline is still
-            gid = int(gid)                # a death, not a straggler
-            self.queue.push(t0, ev.CLIENT_ARRIVAL, gid, r)
-            lat = pop.latency.draw(gid)
-            if rng.random() < pop.dropout[gid]:
-                dropouts.add(gid)
-                t_fail = t0 + lat * rng.uniform(0.1, 0.9)
-                self.queue.push(t_fail, ev.DROPOUT, gid, r)
-                if self.faults.retry:
-                    self._schedule_retries(r, gid, t_fail, lat)
-            else:
-                self.queue.push(t0 + lat, ev.UPDATE_READY, gid, r)
+        with obs.span("round.schedule", round=r):   # dropout past the
+            self.queue.push(t0 + cfg.deadline, ev.BLOCK_SLOT, round_idx=r)
+            for gid in cohort:            # deadline is still a death, not a
+                gid = int(gid)            # straggler
+                self.queue.push(t0, ev.CLIENT_ARRIVAL, gid, r)
+                lat = pop.latency.draw(gid)
+                if rng.random() < pop.dropout[gid]:
+                    dropouts.add(gid)
+                    t_fail = t0 + lat * rng.uniform(0.1, 0.9)
+                    self.queue.push(t_fail, ev.DROPOUT, gid, r)
+                    if self.faults.retry:
+                        self._schedule_retries(r, gid, t_fail, lat)
+                else:
+                    self.queue.push(t0 + lat, ev.UPDATE_READY, gid, r)
 
         arrived_set: set[int] = set()
         with obs.span("round.wait", round=r) as sp:
@@ -595,15 +595,16 @@ class SimulatedFederation:
                     lambda P, rows: P.at[upd_ids].set(rows),
                     self._params, new_rows)
 
-        upd = np.asarray(cohort)[arrived]
-        labels = np.asarray(labels_dev)
-        self.last_labels[upd] = labels[arrived]
+        with obs.span("round.record", round=r):
+            upd = np.asarray(cohort)[arrived]
+            labels = np.asarray(labels_dev)
+            self.last_labels[upd] = labels[arrived]
 
-        record.producer = cres.producer
-        record.verified_frac = float(cres.verified[arrived].mean())
-        record.reward_paid = float(cres.rewards.sum())
-        record.reward_burned = float(cfg.total_reward - cres.rewards.sum())
-        record.mean_loss = float(mean_loss)
+            record.producer = cres.producer
+            record.verified_frac = float(cres.verified[arrived].mean())
+            record.reward_paid = float(cres.rewards.sum())
+            record.reward_burned = float(cfg.total_reward - cres.rewards.sum())
+            record.mean_loss = float(mean_loss)
         if cfg.eval_every and ((r + 1) % cfg.eval_every == 0):
             ex, ey = self._eval_slices()
             if self.engine is not None:
@@ -636,7 +637,7 @@ class SimulatedFederation:
     # ------------------------------------------------------------------ #
 
     def _run_async(self) -> None:
-        cfg, pop, rng = self.cfg, self.pop, self.rng
+        cfg, pop, rng, obs = self.cfg, self.pop, self.rng, self.obs
         if cfg.buffer_size + cfg.concurrency > pop.n_clients:
             # buffered clients stay "busy" until their flush: a buffer that
             # cannot fill from the remaining population stalls forever
@@ -666,6 +667,10 @@ class SimulatedFederation:
             agg = BufferedAggregator(cfg.buffer_size, cfg.staleness_alpha)
 
         def dispatch() -> None:
+            with obs.span("async.dispatch", cat="flush", round=version):
+                _dispatch()
+
+        def _dispatch() -> None:
             want = cfg.concurrency - len(inflight)
             if want <= 0:
                 return
@@ -758,11 +763,12 @@ class SimulatedFederation:
         with obs.span("flush.gather", cat="flush", round=version):
             cx, cy = pop.cohort_data(clients)
 
-        # chain: single-cluster CACC over the flush group
-        labels = jnp.zeros((k,), jnp.int32)
-        corr = jnp.eye(k, dtype=jnp.float32)
-        arrived = np.ones(k, dtype=bool)
-        tamper = self._tampers(clients, arrived)
+        with obs.span("flush.prepare", cat="flush", round=version):
+            # chain: single-cluster CACC over the flush group
+            labels = jnp.zeros((k,), jnp.int32)
+            corr = jnp.eye(k, dtype=jnp.float32)
+            arrived = np.ones(k, dtype=bool)
+            tamper = self._tampers(clients, arrived)
 
         if self.engine is not None:
             layout = self.arena.layout
@@ -782,10 +788,12 @@ class SimulatedFederation:
                     version, None, labels, corr, cohort=clients,
                     arrived=arrived, tamper=tamper,
                     digests=self.engine.format_digests(residues))
-            staleness = np.array([version - v for v in versions], np.int64)
-            w = np.asarray(staleness_weight(staleness, cfg.staleness_alpha),
-                           np.float32) * cres.verified.astype(np.float32)
             with obs.span("flush.merge", cat="flush", round=version):
+                staleness = np.array([version - v for v in versions],
+                                     np.int64)
+                w = np.asarray(staleness_weight(staleness,
+                                                cfg.staleness_alpha),
+                               np.float32) * cres.verified.astype(np.float32)
                 # merge through the SAME jitted collective as the legacy path
                 # (same leaf shapes -> same executable -> bit-identical
                 # replay); the unflatten/flatten round-trips are exact
@@ -828,27 +836,31 @@ class SimulatedFederation:
                 staleness_weight(staleness, cfg.staleness_alpha),
                 np.float32) * cres.verified.astype(np.float32)
 
-        if obs.enabled:
-            # staleness-weight distribution: how much each flush discounts
-            # its stale contributors (and zeroes its unverified ones)
-            for s in staleness:
-                obs.observe("async.staleness", float(s))
-            for wv in staleness_w:
-                obs.observe("async.staleness_weight", float(wv))
-            obs.point("async.staleness_mean", staleness_mean, round=version)
+        with obs.span("flush.record", cat="flush", round=version):
+            if obs.enabled:
+                # staleness-weight distribution: how much each flush
+                # discounts its stale contributors (and zeroes its
+                # unverified ones)
+                for s in staleness:
+                    obs.observe("async.staleness", float(s))
+                for wv in staleness_w:
+                    obs.observe("async.staleness_weight", float(wv))
+                obs.point("async.staleness_mean", staleness_mean,
+                          round=version)
 
-        new_version = version + 1
-        self.last_labels[clients] = 0
-        record = SimRoundRecord(
-            round_idx=version, t_open=self.clock.now, t_close=self.clock.now,
-            cohort=clients, arrived=arrived, n_stragglers=0, n_dropouts=0,
-            n_byzantine=int(pop.byzantine[clients].sum()),
-            producer=cres.producer,
-            verified_frac=float(cres.verified.mean()),
-            reward_paid=float(cres.rewards.sum()),
-            reward_burned=float(cfg.total_reward - cres.rewards.sum()),
-            mean_loss=float(mean_loss),
-            staleness_mean=staleness_mean)
+            new_version = version + 1
+            self.last_labels[clients] = 0
+            record = SimRoundRecord(
+                round_idx=version, t_open=self.clock.now,
+                t_close=self.clock.now, cohort=clients, arrived=arrived,
+                n_stragglers=0, n_dropouts=0,
+                n_byzantine=int(pop.byzantine[clients].sum()),
+                producer=cres.producer,
+                verified_frac=float(cres.verified.mean()),
+                reward_paid=float(cres.rewards.sum()),
+                reward_burned=float(cfg.total_reward - cres.rewards.sum()),
+                mean_loss=float(mean_loss),
+                staleness_mean=staleness_mean)
         if cfg.eval_every and (new_version % cfg.eval_every == 0):
             ex, ey = self._eval_slices()
             if self.engine is not None:

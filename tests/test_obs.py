@@ -1,11 +1,12 @@
 """Unit tests for `repro.obs` — the flight recorder subsystem.
 
 Covers the streaming metrics (deterministic RNG-free reservoir thinning),
-the span tracer (wall + virtual clocks, compile-delta events), the JSONL
-schema validator, and the sinks (digest-stable JSONL, Chrome trace export,
-console summary).  End-to-end replay invariance lives in
+the span tracer (wall + virtual clocks, span ids and parents, profiler
+annotations, compile-delta events), the JSONL schema validator, and the
+sinks (digest-stable JSONL, console summary).  End-to-end replay invariance lives in
 ``tests/test_obs_invariance.py``.
 """
+import glob
 import json
 
 import pytest
@@ -20,7 +21,6 @@ from repro.obs import (
     file_sha256,
     validate_record,
     validate_trace_lines,
-    write_chrome_trace,
     write_jsonl,
 )
 
@@ -38,7 +38,6 @@ def test_obs_spec_defaults_off():
 @pytest.mark.parametrize("bad", [
     dict(trace_path=""),
     dict(sample_cap=4),
-    dict(chrome_path=""),
     dict(profile_dir=""),
 ])
 def test_obs_spec_validates(bad):
@@ -109,6 +108,77 @@ def test_span_records_wall_and_virtual_time():
     assert rec.metrics.summaries["round.total"].count == 1
 
 
+def test_span_ids_and_parents_follow_nesting():
+    rec = FlightRecorder(ObsSpec(enabled=True))
+    with rec.span("round.total", round=0):
+        with rec.span("round.schedule", round=0):
+            pass
+        with rec.span("round.chain", round=0):
+            with rec.span("chain.pack", cat="chain", round=0):
+                pass
+    with rec.span("round.total", round=1):
+        pass
+    by_name = {}
+    for r in rec.records:
+        by_name.setdefault(r["name"], []).append(r)
+    total0, total1 = by_name["round.total"]
+    assert (total0["id"], total0["parent"]) == (0, None)
+    assert by_name["round.schedule"][0]["parent"] == 0
+    assert by_name["round.chain"][0]["parent"] == 0
+    assert by_name["chain.pack"][0]["parent"] == \
+        by_name["round.chain"][0]["id"]
+    assert total1["parent"] is None
+    assert sorted(r["id"] for r in rec.records) == list(range(5))
+    for r in rec.records:
+        validate_record(r)
+
+
+def _host_annotations(log_dir) -> list:
+    from jax.profiler import ProfileData
+    (path,) = glob.glob(str(log_dir / "**" / "*.xplane.pb"), recursive=True)
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for ev in line.events:
+                    if ev.name.startswith(("round.", "chain.")):
+                        out.append((ev.name, ev.start_ns, ev.end_ns,
+                                    dict(ev.stats)))
+    return out
+
+
+@pytest.mark.parametrize("live", [True, False])
+def test_spans_are_profiler_annotations(tmp_path, live):
+    """A live recorder's spans show on the profiler's host plane by name,
+    each inside its parent and carrying its id and parent; the shared no-op
+    recorder leaves no annotation."""
+    import jax
+    rec = FlightRecorder(ObsSpec(enabled=True)) if live else NULL_RECORDER
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with rec.span("round.total", round=0):
+            with rec.span("round.chain", round=0):
+                with rec.span("chain.pack", cat="chain", round=0):
+                    jax.numpy.ones(4).block_until_ready()
+    finally:
+        jax.profiler.stop_trace()
+    events = {name: (s, e, stats)
+              for name, s, e, stats in _host_annotations(tmp_path)}
+    if not live:
+        assert events == {}
+        return
+    ids = {r["name"]: (r["id"], r["parent"]) for r in rec.records}
+    assert set(events) == set(ids)
+    for name, (s, e, stats) in events.items():
+        span_id, parent = ids[name]
+        assert stats["id"] == span_id
+        assert stats.get("parent") == parent
+    for child, parent in (("round.chain", "round.total"),
+                          ("chain.pack", "round.chain")):
+        assert events[parent][0] <= events[child][0]
+        assert events[child][1] <= events[parent][1]
+
+
 def test_compile_delta_emits_events_once_per_growth():
     rec = FlightRecorder(ObsSpec(enabled=True))
     rec.compile_delta({"sync_step": 1, "eval": 0}, round_idx=0)
@@ -157,9 +227,11 @@ def test_timing_summary_reads_round_metrics():
 
 def test_validate_record_accepts_each_kind():
     for rec in [
-        {"kind": "meta", "schema": 1},
-        {"kind": "span", "name": "a", "cat": "round", "round": 1,
-         "ts_us": 0.0, "dur_us": 1.0, "vt": None},
+        {"kind": "meta", "schema": 2},
+        {"kind": "span", "name": "a", "cat": "round", "round": 1, "id": 0,
+         "parent": None, "ts_us": 0.0, "dur_us": 1.0, "vt": None},
+        {"kind": "span", "name": "b", "cat": "round", "round": 1, "id": 3,
+         "parent": 0, "ts_us": 0.0, "dur_us": 1.0, "vt": None},
         {"kind": "event", "name": "compile", "round": None, "ts_us": 2.0},
         {"kind": "point", "name": "p", "round": 0, "value": 1.5},
         {"kind": "summary", "name": "s", "count": 1, "sum": 1.0, "mean": 1.0,
@@ -177,22 +249,49 @@ def test_validate_record_accepts_each_kind():
     {"kind": "counter", "name": "c", "value": "high"},   # non-numeric
     {"kind": "counter", "name": "c", "value": True},     # bool is not a number
     {"kind": "point", "name": 7, "round": 0, "value": 1.0},
+    {"kind": "meta", "schema": 1},                       # old schema
+    {"kind": "span", "name": "a", "cat": "c", "round": None,   # no id
+     "ts_us": 0.0, "dur_us": 1.0, "vt": None},
+    {"kind": "span", "name": "a", "cat": "c", "round": None, "id": 2,
+     "parent": 2, "ts_us": 0.0, "dur_us": 1.0, "vt": None},  # own parent
+    {"kind": "span", "name": "a", "cat": "c", "round": None, "id": -1,
+     "parent": None, "ts_us": 0.0, "dur_us": 1.0, "vt": None},
 ])
 def test_validate_record_rejects(bad):
     with pytest.raises(ValueError):
         validate_record(bad)
 
 
-def test_validate_trace_lines_requires_meta_header():
-    meta = json.dumps({"kind": "meta", "schema": 1})
-    span = json.dumps({"kind": "span", "name": "a", "cat": "c", "round": None,
+def _span_line(span_id: int, parent: int | None) -> str:
+    return json.dumps({"kind": "span", "name": "a", "cat": "c",
+                       "round": None, "id": span_id, "parent": parent,
                        "ts_us": 0.0, "dur_us": 1.0, "vt": None})
+
+
+def test_validate_trace_lines_requires_meta_header():
+    meta = json.dumps({"kind": "meta", "schema": 2})
+    span = _span_line(0, None)
     counts = validate_trace_lines([meta, span])
     assert counts == {"meta": 1, "span": 1}
     with pytest.raises(ValueError):
         validate_trace_lines([span, meta])               # meta must come first
     with pytest.raises(ValueError):
         validate_trace_lines([meta, meta])               # exactly one meta
+
+
+@pytest.mark.parametrize("spans, ok", [
+    ([(1, 0), (0, None)], True),          # a child is written before its parent
+    ([(0, None), (2, 1)], False),         # parent 1 names no span
+    ([(0, None), (0, None)], False),      # duplicate id
+])
+def test_validate_trace_lines_checks_span_parents(spans, ok):
+    lines = [json.dumps({"kind": "meta", "schema": 2})] + \
+        [_span_line(i, p) for i, p in spans]
+    if ok:
+        assert validate_trace_lines(lines) == {"meta": 1, "span": len(spans)}
+    else:
+        with pytest.raises(ValueError):
+            validate_trace_lines(lines)
 
 
 # --------------------------------------------------------------------------- #
@@ -221,21 +320,6 @@ def test_write_jsonl_digest_matches_file_and_schema(tmp_path):
     # byte-determinism: same records -> same file -> same digest
     path2 = str(tmp_path / "t2.jsonl")
     assert write_jsonl(path2, {"seed": 0}, rec.records, rec.metrics) == digest
-
-
-def test_chrome_trace_export(tmp_path):
-    rec = _recorder_with_traffic()
-    path = str(tmp_path / "chrome.json")
-    n = write_chrome_trace(path, rec.records)
-    doc = json.load(open(path))
-    events = doc["traceEvents"]
-    assert n == len(events) == 3                         # 2 spans + 1 instant
-    spans = [e for e in events if e["ph"] == "X"]
-    assert {e["cat"] for e in spans} == {"round", "chain"}
-    # one track per category
-    assert len({e["tid"] for e in spans}) == 2
-    (instant,) = [e for e in events if e["ph"] == "i"]
-    assert instant["name"] == "compile"
 
 
 def test_console_summary_mentions_phases_and_counters():

@@ -95,6 +95,56 @@ def test_trace_records_chain_and_phase_spans(tmp_path):
             "chain.pack", "chain.verify", "run.final_eval"} <= names
 
 
+def _span_records(path) -> list[dict]:
+    import json
+    return [r for r in map(json.loads, open(path)) if r["kind"] == "span"]
+
+
+@pytest.mark.parametrize("mode, outer, children", [
+    ("sync", "round.total", ("round.schedule", "round.record")),
+    ("async", "flush.total", ("flush.prepare", "flush.record")),
+])
+def test_host_work_spans_nest_once_per_round(tmp_path, mode, outer,
+                                             children):
+    """The host work between the older spans has spans of its own: once
+    per round or flush, each a child of its ``round.total`` or
+    ``flush.total``; FedBuff's ``dispatch()`` calls run in the event loop,
+    outside any flush."""
+    trace = str(tmp_path / f"{mode}.jsonl")
+    api.run(_spec(mode=mode, obs=api.ObsSpec(enabled=True,
+                                             trace_path=trace)))
+    spans = _span_records(trace)
+    outers = {r["id"] for r in spans if r["name"] == outer}
+    assert outers
+    for name in children:
+        parents = [r["parent"] for r in spans if r["name"] == name]
+        assert sorted(parents) == sorted(outers), name
+    if mode == "async":
+        dispatch = [r for r in spans if r["name"] == "async.dispatch"]
+        assert len(dispatch) > len(outers)
+        assert all(r["parent"] is None for r in dispatch)
+
+
+@pytest.mark.parametrize("strategy", ["bfln", "fedavg"])
+def test_named_scopes_change_no_bits(monkeypatch, strategy):
+    """The step's named scopes are HLO metadata only: the same run with
+    every ``jax.named_scope`` made a no-op replays bit for bit."""
+    import contextlib
+    import dataclasses
+
+    def spec():
+        s = _spec(mode="sync")
+        return dataclasses.replace(
+            s, train=dataclasses.replace(s.train, strategy=strategy))
+
+    scoped = api.run(spec())
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    plain = api.run(spec())
+    for key in REPLAY_KEYS:
+        assert scoped.manifest[key] == plain.manifest[key], key
+
+
 @mesh8
 @pytest.mark.parametrize("mode", ["sync", "async"])
 def test_traced_replay_identical_mesh8(tmp_path, mode):

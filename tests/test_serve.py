@@ -398,6 +398,35 @@ def test_serve_records_validate_against_trace_schema(result, bank):
     assert isinstance(fe.take_completed()[0], Completion)
 
 
+@pytest.mark.parametrize("arrivals, flush_at", [
+    ((0.0, 0.2, 0.3), 1.0),
+    ((0.5,), 0.75),
+])
+def test_frontend_flush_records_queue_waits(bank, chain, arrivals, flush_at):
+    """``serve.flush`` carries the batch's queue waits on the frontend's
+    clock, from each arrival to the flush's start, and its pack, dispatch
+    and readback are its children."""
+    rec = FlightRecorder(api.ObsSpec(enabled=True))
+    eng = ServingEngine(bank, chain, obs=rec)
+    clock = VirtualClock()
+    fe = ServeFrontend(eng, ServeConfig(buckets=(8,), max_wait=0.25),
+                       clock=clock, obs=rec)
+    x = np.zeros(bank.mcfg.in_dim, np.float32)
+    for t in arrivals:
+        clock.advance_to(t)
+        fe.submit(0, x)
+    clock.advance_to(flush_at)
+    fe.pump()
+    (flush,) = [r for r in rec.records if r["name"] == "serve.flush"]
+    waits = [(flush_at - t) * 1e6 for t in arrivals]
+    assert flush["attrs"]["wait_sum_us"] == pytest.approx(sum(waits))
+    assert flush["attrs"]["wait_max_us"] == pytest.approx(max(waits))
+    assert flush["attrs"]["n"] == len(arrivals)
+    children = [r["name"] for r in rec.records
+                if r["kind"] == "span" and r["parent"] == flush["id"]]
+    assert children == ["serve.pack", "serve.batch", "serve.readback"]
+
+
 def test_bank_types(bank):
     assert isinstance(bank, ModelBank)
     assert bank.nbytes == bank.data.size * 4
