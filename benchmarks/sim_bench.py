@@ -32,17 +32,19 @@ def _warm(sim: SimulatedFederation) -> None:
     else:
         k = cfg.buffer_size
     cohort = np.arange(k)
-    cx, cy = pop.cohort_data(cohort)
     if sim.engine is not None:
-        # arena engine: warm the fused step, then rebind (donated input)
+        # arena engine: warm the fused step with the argument forms the
+        # driver passes, then rebind (donated input)
         if cfg.mode == "sync":
             sim.arena.data, out = sim.engine.sync_step(
-                sim.arena.data, jnp.asarray(cohort), cx, cy,
-                jnp.zeros((k,), jnp.float32))   # zero mask: no-op scatter
+                sim.arena.data, cohort, *sim.step_data,
+                np.zeros(k, np.float32))        # zero mask: no-op scatter
             out = out.residues
         else:
-            out, _, _ = sim.engine.async_step(sim.arena.data[:k], cx, cy)
+            out, _, _ = sim.engine.async_step(
+                [sim.arena.data[0]] * k, *sim.flush_data(cohort))
     else:
+        cx, cy = pop.cohort_data(cohort)
         params = jax.tree.map(lambda x: x[:k], sim.params)
         if cfg.mode == "sync":
             out = sim._cohort_round(params, cx, cy, jnp.ones((k,), jnp.float32))

@@ -113,9 +113,8 @@ def _last_cohort_args(sim):
     """The last round's sync_step arguments (the arena is not consumed:
     these are only lowered)."""
     rec = next(r for r in reversed(sim.history) if r.arrived.any())
-    cx, cy = sim.pop.cohort_data(rec.cohort)
-    return (sim.arena.data, jnp.asarray(rec.cohort), cx, cy,
-            jnp.asarray(rec.arrived, jnp.float32)), rec.cohort
+    return (sim.arena.data, rec.cohort, *sim.step_data,
+            rec.arrived.astype(np.float32)), rec.cohort
 
 
 def _kernel_rows(hlo: str) -> list[int]:

@@ -77,27 +77,33 @@ def _build_probe(mesh_shards: int, n_clients: int = 32, cohort_k: int = 8):
             seed=7, engine=True, mesh_shards=mesh_shards))
 
     k = cohort_k
-    cohort = jnp.arange(k)
-    cx, cy = pop.cohort_data(np.arange(k))
-    arrived = jnp.ones((k,), jnp.float32)
+    cohort = np.arange(k)
+    data_x, data_y = sim.step_data
+    cx, cy = sim.flush_data(cohort)
+    arrived = np.ones((k,), np.float32)
     ex, ey = pop.test_x[:32], pop.test_y[:32]
     # replicated (k, N) rows, exactly like the driver's flush snapshots
     rows = jnp.asarray(np.asarray(sim.arena.data[:k]))
     labels = jnp.zeros((k,), jnp.int32)
+    staleness = np.arange(k) % 3
+    verified = np.ones((k,), np.float32)
 
     entry_args = {
-        "sync_step": (sim.arena.data, cohort, cx, cy, arrived),
+        "sync_step": (sim.arena.data, cohort, data_x, data_y, arrived),
         "async_step": (rows, cx, cy),
+        "async_merge": (rows[0], rows, rows, staleness, verified, 0.5, 1.0),
         "eval_cohort": (rows, arrived, labels, ex, ey),
         "eval_global": (rows[0], ex, ey),
         "eval_population": (sim.arena.data, cohort, ex, ey),
     }
     # same shapes, different values — must NOT retrace
     varied = {
-        "sync_step": (sim.arena.data, cohort,
-                      cx, cy, arrived.at[0].set(0.0)),
+        "sync_step": (sim.arena.data, cohort[::-1],
+                      data_x, data_y, np.r_[0.0, arrived[1:]]),
         "async_step": (rows, cx, cy),
-        "eval_cohort": (rows, arrived.at[0].set(0.0),
+        "async_merge": (rows[1], rows, rows[::-1], staleness[::-1],
+                        np.r_[0.0, verified[1:]], 0.25, 0.5),
+        "eval_cohort": (rows, np.r_[0.0, arrived[1:]],
                         labels.at[0].set(1), ex, ey),
         "eval_global": (rows[1], ex, ey),
         "eval_population": (sim.arena.data, cohort[::-1], ex, ey),

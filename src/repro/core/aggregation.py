@@ -95,6 +95,31 @@ def masked_tree_sum(x: jax.Array, w: jax.Array, axis: int = 0) -> jax.Array:
     return tree_sum(contrib, axis=axis)
 
 
+# FedBuff merge: staleness weights and the weighted mean of buffered deltas,
+# shared by the engine's flat merge entry and the legacy driver
+
+def staleness_weight(staleness, alpha: float = 0.5) -> jax.Array:
+    """(1 + s)^(-alpha); alpha=0 disables staleness discounting."""
+    s = jnp.asarray(staleness, jnp.float32)
+    return (1.0 + s) ** (-alpha)
+
+
+@jax.jit
+def weighted_delta_mean(stacked_deltas: Pytree, weights: jax.Array) -> Pytree:
+    """Normalised weighted mean over the leading buffer axis, via the
+    deterministic fixed-order tree (zero-weight slots are where-guarded to
+    exactly +0.0, denominator clamped like the single-cluster collective it
+    replaced)."""
+    w = weights.astype(jnp.float32)
+    denom = jnp.maximum(tree_sum(w), 1e-9)
+
+    def leaf(x):
+        return (masked_tree_sum(x.astype(jnp.float32), w) / denom) \
+            .astype(x.dtype)
+
+    return jax.tree.map(leaf, stacked_deltas)
+
+
 def tree_cluster_mean_params(stacked_params: Pytree, labels: jax.Array,
                              n_clusters: int,
                              weights: jax.Array | None = None) -> Pytree:

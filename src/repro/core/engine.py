@@ -10,10 +10,10 @@ jit recompile per distinct count.
 The engine collapses all of it into ONE jitted, ``donate_argnums``-donated
 program per (mode, cohort_size):
 
-    arena gather → local_train → strategy cohort aggregation (BFLN: PAA —
-    prototypes, Pearson, spectral, cluster-masked mean; baselines:
-    mask-weighted means / personal models) → cohort fingerprint residues →
-    masked scatter-back into the donated arena
+    arena and data gather → local_train → strategy cohort aggregation
+    (BFLN: PAA — prototypes, Pearson, spectral, cluster-masked mean;
+    baselines: mask-weighted means / personal models) → cohort fingerprint
+    residues → masked scatter-back into the donated arena
 
 The engine is **strategy-generic**: every registered strategy
 (`repro.api.registry`) fuses into the same donated step through its
@@ -24,9 +24,16 @@ single-cluster CACC view (labels = zeros, affinity = identity).
 Arrival is a fixed-shape mask everywhere — no ``np.flatnonzero`` dynamic
 indexing, no varying leading dims — so the jit cache hits every round and
 the arena buffer is updated in place (donation) instead of reallocating
-O(n_clients · N_params) bytes.  Only O(cohort) bytes cross the host
-boundary per round: fingerprint residues, cluster labels, the Pearson
-matrix for CACC, and scalar loss/accuracy.
+O(n_clients · N_params) bytes.  The cohort's training data is gathered
+inside the step from the population's device-resident data, with the same
+index as its arena rows, so the host sends only the cohort ids and arrival
+mask (as NumPy; the jit transfers them) and reads back O(cohort) bytes:
+fingerprint residues, cluster labels, the Pearson matrix for CACC, and
+scalar loss/accuracy.
+
+The async (FedBuff) path has two entries: ``async_step`` trains the
+flushed buffer and fingerprints it, and ``async_merge`` is the whole
+staleness-weighted merge over flat ``(k, N)`` rows in one program.
 
 Evaluation entries are split so each compiles exactly once: a fixed-shape
 mask-weighted cohort eval (round metric), a single-row global eval (async),
@@ -65,6 +72,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.aggregation import staleness_weight, weighted_delta_mean
 from repro.core.baselines import barrier_combine_inputs
 from repro.core.fl import local_train
 from repro.kernels.fingerprint import (
@@ -88,6 +96,18 @@ class SyncRoundOut(NamedTuple):
     new_rows: jax.Array     # (k, N) the cohort's post-scatter arena rows —
                             # eval reads THESE, never the full arena, so the
                             # next round's donation has no pending consumer
+
+
+def _unfused(x: jax.Array, scalar: jax.Array) -> jax.Array:
+    """``x`` unchanged, but behind an integer no-op that XLA cannot fold
+    (``scalar != scalar`` is 0 at run time), so an add reading it is not
+    contracted with the multiply that made ``x`` into one fused multiply-add
+    (CPU codegen does so even across an optimization barrier).  A fused
+    multiply-add rounds once where separate ops round twice, which moves
+    bits wherever the multiplier is not 1."""
+    zero = (scalar != scalar).astype(jnp.int32)
+    bits = jax.lax.bitcast_convert_type(x, jnp.int32) ^ zero
+    return jax.lax.bitcast_convert_type(bits, x.dtype)
 
 
 class RoundEngine:
@@ -217,20 +237,24 @@ class RoundEngine:
                 return extras
             return jax.tree.map(lambda e: _pad0(e, pad), extras)
 
-        def _sync_step(arena, cohort_idx, cx, cy, arrived):
+        def _sync_step(arena, cohort_idx, data_x, data_y, arrived):
+            """``data_x``/``data_y``: the whole population's train data,
+            placed like the arena's rows; the cohort's slice is gathered
+            here with the rows' own index."""
             k = cohort_idx.shape[0]
             pad = _cohort_pad(k)
             if sharded_cohort:
                 with jax.named_scope("gather"):
                     # padding slots gather row 0 (any valid row — their
                     # outputs are sliced away and their arrival weight is
-                    # zero)
+                    # zero) and train on zero data
                     idx_p = jnp.concatenate(
                         [cohort_idx, jnp.zeros((pad,), cohort_idx.dtype)]) \
                         if pad else cohort_idx
                     # shard-aware gather: each device receives only its
                     # cohort slice — no replicated (k, N) block materialises
                     rows = _csh(arena[idx_p])
+                    cx, cy = data_x[cohort_idx], data_y[cohort_idx]
                     cx_p, cy_p = _csh(_pad0(cx, pad)), _csh(_pad0(cy, pad))
                     arrived_p = _pad0(arrived, pad)
                 # server payload on the replicated REAL slots with the exact
@@ -290,6 +314,8 @@ class RoundEngine:
             else:
                 with jax.named_scope("gather"):
                     rows = _rep(arena[cohort_idx])
+                    cx = _rep(data_x[cohort_idx])
+                    cy = _rep(data_y[cohort_idx])
                 with jax.named_scope("local_train"):
                     extras = strategy.round_extras(layout.unflatten(rows),
                                                    cx, cy)
@@ -320,11 +346,12 @@ class RoundEngine:
 
         def _async_step(base_rows, cx, cy):
             """FedBuff flush batch: local updates + digests, no aggregation.
-            The merge is gated by chain verification (a host decision) and
-            reuses the same jitted ``weighted_delta_mean`` collective as the
-            legacy driver — a fixed-order tree over replicated buffer rows,
-            so sharing the executable keeps replay bit-identical across
-            engine on/off and across mesh widths."""
+            ``base_rows`` is the (k, N) rows each buffered client trained
+            from, or those k (N,) rows as a sequence (the driver passes its
+            version snapshots), stacked here.  The merge is gated by chain
+            verification (a host decision) and runs afterwards in
+            ``async_merge``."""
+            base_rows = jnp.asarray(base_rows)
             k = base_rows.shape[0]
             pad = _cohort_pad(k)
             if sharded_cohort:
@@ -353,6 +380,23 @@ class RoundEngine:
             local_rows = layout.flatten(res.params)
             residues = _fingerprint(_rep(local_rows))
             return local_rows, residues, jnp.mean(res.mean_loss)
+
+        def _async_merge(global_row, local_rows, base_rows, staleness,
+                         verified, alpha, server_lr):
+            """The whole FedBuff merge in one program over flat (k, N) rows:
+            deltas, the (1 + s)^-alpha weights gated by the chain's verdicts,
+            their fixed-order weighted mean (``weighted_delta_mean`` on one
+            (k, N) leaf — elementwise the same tree as per leaf, so the bits
+            equal the legacy driver's per-leaf merge) and the server update.
+            Runs replicated, so replay is bit-identical across mesh widths.
+            Returns the new global row and the applied weights."""
+            delta = _rep(jnp.asarray(local_rows)) \
+                - _rep(jnp.asarray(base_rows))
+            w = staleness_weight(staleness, alpha) \
+                * jnp.asarray(verified, jnp.float32)
+            merged = weighted_delta_mean(delta, w)
+            step = _unfused(server_lr * merged, server_lr)
+            return _rep(global_row) + step, w
 
         def _eval_cohort(cohort_rows, arrived, labels, ex, ey):
             """Fixed-shape mask-weighted cohort accuracy (the jnp-generic
@@ -397,6 +441,8 @@ class RoundEngine:
 
         self.sync_step = jax.jit(_sync_step, donate_argnums=(0,))
         self.async_step = jax.jit(_async_step)
+        # the global row it reads is also a live version snapshot: no donation
+        self.async_merge = jax.jit(_async_merge, donate_argnums=())
         self.eval_cohort = jax.jit(_eval_cohort)
         self.eval_global = jax.jit(_eval_global)
         self.eval_population = jax.jit(_eval_population)
@@ -404,6 +450,7 @@ class RoundEngine:
         self._entries = {
             "sync_step": self.sync_step,
             "async_step": self.async_step,
+            "async_merge": self.async_merge,
             "eval_cohort": self.eval_cohort,
             "eval_global": self.eval_global,
             "eval_population": self.eval_population,

@@ -60,6 +60,8 @@ COUNTER_NAMES = frozenset({
     "ckpt.saved", "ckpt.restored", "fault.retry", "fault.retry_recovered",
     "serve.requests", "serve.rejected", "serve.batches", "serve.releases",
     "serve.verifications",
+    # calls of the round engine's compiled entries from the round loop
+    "engine.sync_step", "engine.async_step", "engine.async_merge",
 }) | (FAULT_EVENT_NAMES - {"fault.commit_delivered_late"})
 
 # --- gauges: last-written values (recorder.set_gauge) --------------------- #

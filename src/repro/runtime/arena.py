@@ -181,6 +181,12 @@ class ParamArena:
         entry; the hot path donates ``data`` through the engine instead)."""
         self.data = flat
 
+    def place_rows(self, x) -> jax.Array:
+        """A per-client array (leading axis = client id), placed where the
+        arena's rows live, so the round step gathers a cohort's slice of it
+        with the rows' own index."""
+        return jnp.asarray(x)
+
     def as_pytree(self, rows: jax.Array | None = None) -> Pytree:
         """Pytree view of ``rows`` (default: the whole population)."""
         return self.layout.unflatten(self.data if rows is None else rows)
@@ -252,7 +258,7 @@ class ShardedParamArena(ParamArena):
         n_padded = -(-n_clients // shards) * shards
         if n_padded != flat.shape[0]:
             flat = jnp.concatenate(
-                [flat, jnp.zeros((n_padded - flat.shape[0], flat.shape[1]),
+                [flat, jnp.zeros((n_padded - flat.shape[0],) + flat.shape[1:],
                                  flat.dtype)])
         return flat
 
@@ -277,6 +283,15 @@ class ShardedParamArena(ParamArena):
         if rows is None:
             rows = self.data[: self._n_clients]      # drop padding rows
         return self.layout.unflatten(rows)
+
+    def place_rows(self, x) -> jax.Array:
+        """Row-shard a per-client array like the arena (zero-padded to the
+        same row count), once: the step's cohort gather then takes the
+        same shard-aware path as the rows, where a single-device array
+        would be copied to every device on every call."""
+        x = jnp.asarray(x)
+        return jax.device_put(self._pad_rows(x, self._n_clients, self.mesh),
+                              self.sharding)
 
     def per_device_bytes(self) -> int:
         """Arena bytes resident on ONE device (the scaling headline)."""

@@ -12,10 +12,11 @@ trained against version ``v`` but merges at version ``v'`` has staleness
 so slow clients still contribute but cannot drag the model backwards.
 
 The merge itself is the repo's one true weighted-mean collective — the
-fixed-order tree reduction from ``repro.core.aggregation`` — so the jittable
-inner program is shared by the fused engine and the legacy driver, and it
-always runs on replicated (host-staged) buffer rows, which keeps async
-seeded replay identical across mesh widths.  Chain
+fixed-order tree reduction ``repro.core.aggregation.weighted_delta_mean`` —
+which the legacy driver applies per leaf here and the fused engine's
+``async_merge`` applies to flat (k, N) rows, elementwise the same tree, so
+both give the same bits; it always runs on replicated buffer rows, which
+keeps async seeded replay identical across mesh widths.  Chain
 integration is the caller's job: the driver gates merge weights with CACC
 verification, so tampered updates carry zero weight *and* zero reward.
 """
@@ -24,37 +25,13 @@ from __future__ import annotations
 from dataclasses import dataclass, field
 from typing import Any
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.aggregation import masked_tree_sum, tree_sum
+from repro.core.aggregation import staleness_weight, weighted_delta_mean
 from repro.utils.tree import tree_stack
 
 Pytree = Any
-
-
-def staleness_weight(staleness: jax.Array | np.ndarray,
-                     alpha: float = 0.5) -> jax.Array:
-    """(1 + s)^(-alpha); alpha=0 disables staleness discounting."""
-    s = jnp.asarray(staleness, jnp.float32)
-    return (1.0 + s) ** (-alpha)
-
-
-@jax.jit
-def weighted_delta_mean(stacked_deltas: Pytree, weights: jax.Array) -> Pytree:
-    """Normalised weighted mean over the leading buffer axis, via the
-    deterministic fixed-order tree (zero-weight slots are where-guarded to
-    exactly +0.0, denominator clamped like the single-cluster collective it
-    replaced)."""
-    w = weights.astype(jnp.float32)
-    denom = jnp.maximum(tree_sum(w), 1e-9)
-
-    def leaf(x):
-        return (masked_tree_sum(x.astype(jnp.float32), w) / denom) \
-            .astype(x.dtype)
-
-    return jax.tree.map(leaf, stacked_deltas)
 
 
 @dataclass(frozen=True)

@@ -13,9 +13,9 @@ canonical form, see `repro.api.run`) or the flat legacy :class:`SimConfig`
     2. cohort events scheduled on the virtual clock (arrival, update-ready
        after per-client latency, dropout), block slot closes the round,
     3. ONE fused, buffer-donated jitted step (`repro.core.engine`): arena
-       gather → local training → PAA (arrival mask = aggregation weights) →
-       cohort fingerprint digests → masked scatter-back into the donated
-       parameter arena (`repro.runtime.arena`),
+       and data gather → local training → PAA (arrival mask = aggregation
+       weights) → cohort fingerprint digests → masked scatter-back into the
+       donated parameter arena (`repro.runtime.arena`),
     4. `FederatedTrainer.chain_round` runs the full blockchain protocol over
        the cohort — hash commits, CACC packing queue, block, verification,
        participation-aware reward settlement on the population-wide ledger.
@@ -74,7 +74,6 @@ from repro.sim.async_agg import (
     BufferedAggregator,
     BufferedUpdate,
     staleness_weight,
-    weighted_delta_mean,
 )
 from repro.sim.clock import VirtualClock
 from repro.sim.events import EventQueue
@@ -279,6 +278,9 @@ class SimulatedFederation:
 
         self.arena: ParamArena | None = None
         self.engine: RoundEngine | None = None
+        self.step_data: tuple | None = None   # (cx, cy) where the step reads
+        self._host_data: tuple | None = None  # (cx, cy) on the host (async)
+        self._eval_data: tuple | None = None
         self.params = clf.init_stacked(mcfg, jax.random.PRNGKey(config.seed), n)
         # shared tamper digest for Byzantine commits (built once; chain_round
         # substitutes the digest each freerider *claims*, which never varies)
@@ -344,6 +346,10 @@ class SimulatedFederation:
                 stacked_apply_fn=functools.partial(clf.apply_stacked, mcfg),
                 sharding=getattr(self.arena, "sharding", None),
                 cohort_mode=config.mesh_cohort)
+            # the population's train data, placed once like the arena rows:
+            # sync_step gathers each cohort's slice of it on the device
+            self.step_data = (self.arena.place_rows(population.cx),
+                              self.arena.place_rows(population.cy))
             if self.obs.enabled:
                 self.obs.set_gauge("arena.bytes", int(self.arena.data.nbytes))
                 per_dev = getattr(self.arena, "per_device_bytes", None)
@@ -463,8 +469,20 @@ class SimulatedFederation:
             self.queue.push(t_retry, ev.DROPOUT, gid, r)
 
     def _eval_slices(self) -> tuple[jnp.ndarray, jnp.ndarray]:
-        return (self.pop.test_x[: self.cfg.eval_examples],
-                self.pop.test_y[: self.cfg.eval_examples])
+        if self._eval_data is None:       # sliced once, not every eval round
+            self._eval_data = (self.pop.test_x[: self.cfg.eval_examples],
+                               self.pop.test_y[: self.cfg.eval_examples])
+        return self._eval_data
+
+    def flush_data(self, clients: np.ndarray) -> tuple:
+        """A flush's train data, gathered on the host from one host copy of
+        the population's: the jit transfers it, so no eager device op runs
+        (a flush holds ``buffer_size`` clients, a few tens of KB)."""
+        if self._host_data is None:
+            self._host_data = (np.asarray(self.pop.cx),
+                               np.asarray(self.pop.cy))
+        cx, cy = self._host_data
+        return cx[clients], cy[clients]
 
     def _evaluate_clients(self, ids: np.ndarray) -> float:
         ex, ey = self._eval_slices()
@@ -545,19 +563,18 @@ class SimulatedFederation:
             obs.inc("rounds.empty")
             return record                     # empty round: no block minted
 
-        with obs.span("round.gather", round=r):
-            cx, cy = pop.cohort_data(cohort)
-        arrived_w = jnp.asarray(arrived, jnp.float32)
+        arrived_w = arrived.astype(np.float32)
 
         if self.engine is not None:
-            # ONE donated device program: gather → train → PAA → digests →
-            # masked scatter-back; the host sees only O(cohort) bytes
-            cohort_idx = jnp.asarray(cohort)
+            # ONE donated device program: row and data gather → train → PAA
+            # → digests → masked scatter-back.  The cohort ids and arrival
+            # mask go in as NumPy; the host reads back O(cohort) bytes
             with obs.span("round.step", round=r,
                           shards=self.engine.cohort_shards,
                           cohort_mode=self.engine.cohort_mode):
                 self.arena.data, out = self.engine.sync_step(
-                    self.arena.data, cohort_idx, cx, cy, arrived_w)
+                    self.arena.data, cohort, *self.step_data, arrived_w)
+                obs.inc("engine.sync_step")
                 obs.ready(out)
             if obs.enabled:
                 obs.compile_delta(self.engine.cache_sizes(), r)
@@ -571,6 +588,8 @@ class SimulatedFederation:
                     arrived=arrived, tamper=self._tampers(cohort, arrived),
                     digests=digests)
         else:
+            with obs.span("round.gather", round=r):
+                cx, cy = pop.cohort_data(cohort)
             with obs.span("round.step", round=r):
                 cohort_params = jax.tree.map(lambda x: x[jnp.asarray(cohort)],
                                              self._params)
@@ -760,25 +779,24 @@ class SimulatedFederation:
         clients = np.array([u.client for u in agg.buffer], dtype=np.int64)
         versions = [u.version for u in agg.buffer]
         k = len(clients)
-        with obs.span("flush.gather", cat="flush", round=version):
-            cx, cy = pop.cohort_data(clients)
 
         with obs.span("flush.prepare", cat="flush", round=version):
-            # chain: single-cluster CACC over the flush group
-            labels = jnp.zeros((k,), jnp.int32)
-            corr = jnp.eye(k, dtype=jnp.float32)
+            # chain: single-cluster CACC over the flush group (host
+            # constants; the chain's jitted consumers take NumPy)
+            labels = np.zeros(k, np.int32)
+            corr = np.eye(k, dtype=np.float32)
             arrived = np.ones(k, dtype=bool)
             tamper = self._tampers(clients, arrived)
 
         if self.engine is not None:
-            layout = self.arena.layout
             with obs.span("flush.step", cat="flush", round=version,
                           shards=self.engine.cohort_shards,
                           cohort_mode=self.engine.cohort_mode):
-                base_rows = jnp.stack(
-                    [snapshots[v] for v in versions])          # (k, N)
+                # each client's dispatch snapshot, stacked inside the step
+                base_rows = [snapshots[v] for v in versions]
                 local_rows, residues, mean_loss = self.engine.async_step(
-                    base_rows, cx, cy)
+                    base_rows, *self.flush_data(clients))
+                obs.inc("engine.async_step")
                 obs.ready(local_rows)
             if obs.enabled:
                 obs.compile_delta(self.engine.cache_sizes(), version)
@@ -791,23 +809,19 @@ class SimulatedFederation:
             with obs.span("flush.merge", cat="flush", round=version):
                 staleness = np.array([version - v for v in versions],
                                      np.int64)
-                w = np.asarray(staleness_weight(staleness,
-                                                cfg.staleness_alpha),
-                               np.float32) * cres.verified.astype(np.float32)
-                # merge through the SAME jitted collective as the legacy path
-                # (same leaf shapes -> same executable -> bit-identical
-                # replay); the unflatten/flatten round-trips are exact
-                # reshapes
-                deltas = layout.unflatten(local_rows - base_rows)
-                merged = weighted_delta_mean(deltas, jnp.asarray(w))
-                merged_row = layout.flatten(
-                    jax.tree.map(lambda x: x[None], merged))[0]
-                global_state = global_state + cfg.server_lr * merged_row
+                # one program over the flat rows, bit-identical to the
+                # legacy driver's per-leaf weighted_delta_mean merge
+                global_state, staleness_w = self.engine.async_merge(
+                    global_state, local_rows, base_rows, staleness,
+                    cres.verified.astype(np.float32), cfg.staleness_alpha,
+                    cfg.server_lr)
+                obs.inc("engine.async_merge")
                 obs.ready(global_state)
             agg.buffer = []
             staleness_mean = float(staleness.mean())
-            staleness_w = w
         else:
+            with obs.span("flush.gather", cat="flush", round=version):
+                cx, cy = pop.cohort_data(clients)
             with obs.span("flush.step", cat="flush", round=version):
                 base = tree_stack([snapshots[v] for v in versions])
                 local_params, mean_loss = self._local_only(base, cx, cy)
@@ -843,7 +857,7 @@ class SimulatedFederation:
                 # unverified ones)
                 for s in staleness:
                     obs.observe("async.staleness", float(s))
-                for wv in staleness_w:
+                for wv in np.asarray(staleness_w):
                     obs.observe("async.staleness_weight", float(wv))
                 obs.point("async.staleness_mean", staleness_mean,
                           round=version)

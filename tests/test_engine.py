@@ -142,11 +142,10 @@ def test_engine_eval_matches_generic_masked_reference():
     pop = _pop(n=30)
     sim = _sim(pop, engine=True, rounds=1)
     k = 8
-    cohort_idx = jnp.arange(k)
-    cx, cy = pop.cohort_data(np.arange(k))
-    mask = jnp.asarray([1, 1, 0, 1, 0, 1, 1, 1], jnp.float32)
+    cohort_idx = np.arange(k)
+    mask = np.asarray([1, 1, 0, 1, 0, 1, 1, 1], np.float32)
     sim.arena.data, out = sim.engine.sync_step(
-        sim.arena.data, cohort_idx, cx, cy, mask)
+        sim.arena.data, cohort_idx, *sim.step_data, mask)
     ex, ey = sim._eval_slices()
     acc, cacc = sim.engine.eval_cohort(out.new_rows, mask, out.labels, ex, ey)
     ref_acc, ref_accs = masked_global_evaluate(
@@ -170,7 +169,7 @@ def test_sync_step_zero_arrival_cluster_matches_legacy():
     # discover the round's labels (mask-independent), then craft an arrival
     # mask that leaves one whole cluster empty
     _, probe_out = ea.engine.sync_step(
-        ea.arena.data, cohort_idx, cx, cy, jnp.ones((k,), jnp.float32))
+        ea.arena.data, cohort_idx, *ea.step_data, np.ones(k, np.float32))
     labels = np.asarray(probe_out.labels)
     dead = labels[0]
     mask = (labels != dead)
@@ -181,7 +180,7 @@ def test_sync_step_zero_arrival_cluster_matches_legacy():
     eb = _sim(pop, engine=False, rounds=1)
     arrived_w = jnp.asarray(mask, jnp.float32)
     new_data, out = ea.engine.sync_step(
-        ea.arena.data, cohort_idx, cx, cy, arrived_w)
+        ea.arena.data, cohort_idx, *ea.step_data, arrived_w)
 
     local_params, agg, mean_loss = eb._cohort_round(
         jax.tree.map(lambda x: x[cohort_idx], eb.params), cx, cy, arrived_w)
@@ -197,3 +196,132 @@ def test_sync_step_zero_arrival_cluster_matches_legacy():
     np.testing.assert_array_equal(
         np.asarray(new_data).view(np.uint32),
         np.asarray(ea.arena.layout.flatten(expect)).view(np.uint32))
+
+
+# --------------------------------------------------------------------------- #
+# the round loop's device work lives in the engine's entries
+# --------------------------------------------------------------------------- #
+
+def _same_bits(a, b):
+    a, b = np.asarray(a), np.asarray(b)
+    assert a.dtype == b.dtype and a.shape == b.shape
+    assert a.tobytes() == b.tobytes()
+
+
+def test_sync_step_gathers_the_cohort_data_in_step():
+    """The step gathers the cohort's data from the whole population's with
+    the rows' index: bit-identical to a step given ``pop.cohort_data``
+    through an identity index over the cohort's own rows."""
+    pop = _pop(n=40)
+    sim = _sim(pop, engine=True, rounds=1)
+    k = 10
+    cohort = np.random.default_rng(0).choice(40, k, replace=False)
+    arrived = (np.arange(k) % 4 != 1).astype(np.float32)
+    own_rows = jnp.asarray(np.asarray(sim.arena.data)[cohort])
+    arena_a, out_a = sim.engine.sync_step(
+        jnp.copy(sim.arena.data), cohort, *sim.step_data, arrived)
+    arena_b, out_b = sim.engine.sync_step(
+        own_rows, np.arange(k), *pop.cohort_data(cohort), arrived)
+    for a, b in zip(out_a, out_b):
+        _same_bits(a, b)
+    _same_bits(np.asarray(arena_a)[cohort], arena_b)
+
+
+def test_async_step_trains_on_the_flush_data():
+    """A flush's host-gathered data and its snapshot rows passed as a
+    sequence give the step's outputs bit for bit as ``pop.cohort_data`` and
+    one stacked (k, N) array do."""
+    pop = _pop(n=40)
+    sim = _sim(pop, engine=True, rounds=1, mode="async", buffer_size=5,
+               concurrency=10)
+    clients = np.array([3, 17, 8, 30, 21])
+    rows = [sim.arena.data[i] for i in (0, 0, 1, 0, 2)]
+    got = sim.engine.async_step(rows, *sim.flush_data(clients))
+    want = sim.engine.async_step(jnp.stack(rows), *pop.cohort_data(clients))
+    for a, b in zip(got, want):
+        _same_bits(a, b)
+
+
+@pytest.mark.parametrize("server_lr", [1.0, 0.7])
+@pytest.mark.parametrize("verified", [[1, 0, 1, 1, 0, 1], [0] * 6],
+                         ids=["some_refused", "all_refused"])
+def test_async_merge_equals_the_per_leaf_merge(server_lr, verified):
+    """The flat merge entry against the legacy form, bit for bit: the
+    eager staleness weights gated by the verdicts, ``weighted_delta_mean``
+    over the unflattened deltas, flattened back, and the server update."""
+    from repro.sim import staleness_weight, weighted_delta_mean
+    sim = _sim(_pop(n=30), engine=True, rounds=1)
+    layout = sim.arena.layout
+    rng = np.random.default_rng(1)
+    k, n = 6, layout.n_params
+    local = rng.standard_normal((k, n)).astype(np.float32)
+    base = rng.standard_normal((k, n)).astype(np.float32)
+    glob = rng.standard_normal(n).astype(np.float32)
+    staleness = np.arange(k)                       # 0..5
+    verified = np.asarray(verified, np.float32)
+
+    new, w = sim.engine.async_merge(glob, local, list(base), staleness,
+                                    verified, 0.5, server_lr)
+
+    w_ref = np.asarray(staleness_weight(staleness, 0.5), np.float32) \
+        * verified
+    merged = weighted_delta_mean(
+        layout.unflatten(jnp.asarray(local) - jnp.asarray(base)),
+        jnp.asarray(w_ref))
+    row = layout.flatten(jax.tree.map(lambda x: x[None], merged))[0]
+    _same_bits(w, w_ref)
+    _same_bits(new, jnp.asarray(glob) + server_lr * row)
+
+
+@pytest.mark.parametrize("mode", ["sync", "async"])
+def test_steady_state_round_issues_no_eager_device_op(monkeypatch, mode):
+    """After warm-up a sync round (an eval round among them) or a FedBuff
+    flush runs no eager JAX primitive: its device work is the engine's
+    entries, one ``sync_step`` a round, one ``async_step`` and one
+    ``async_merge`` a flush, as the recorder counts them."""
+    from jax._src import dispatch
+
+    from repro.obs import FlightRecorder
+    if mode == "sync":
+        sim = _sim(_pop(n=60), engine=True, rounds=6, eval_every=2)
+    else:
+        sim = _sim(_pop(n=60), engine=True, rounds=8, eval_every=2,
+                   mode="async", buffer_size=5, concurrency=20)
+    sim.obs = FlightRecorder()
+    eager, watching = [], [False]
+    primitive_callable = dispatch.xla_primitive_callable
+
+    def spy(prim, **params):
+        if watching[0]:
+            eager.append(prim.name)
+        return primitive_callable(prim, **params)
+
+    monkeypatch.setattr(dispatch, "xla_primitive_callable", spy)
+    counters = sim.obs.metrics.counters
+    if mode == "sync":
+        for r in range(3):
+            sim.history.append(sim._run_sync_round(r))
+        before = counters.get("engine.sync_step", 0)
+        watching[0] = True
+        for r in range(3, 6):                       # r = 3, 5: eval rounds
+            sim.history.append(sim._run_sync_round(r))
+        watching[0] = False
+        stepped = sum(rec.arrived.any() for rec in sim.history[3:])
+        assert stepped == 3
+        assert counters["engine.sync_step"] - before == stepped
+    else:
+        flush = sim._async_flush
+
+        def watched(*args):
+            watching[0] = len(sim.history) >= 3
+            try:
+                return flush(*args)
+            finally:
+                watching[0] = False
+
+        monkeypatch.setattr(sim, "_async_flush", watched)
+        sim._run_async()
+        assert len(sim.history) == 8
+        assert counters["engine.async_step"] == 8
+        assert counters["engine.async_merge"] == 8
+    assert eager == []

@@ -255,24 +255,24 @@ def test_sharded_zero_arrival_cluster_matches_single_device():
     on the mesh: weight-zero mean, members keep their old (sharded) rows."""
     pop = _pop(n=40, straggler_frac=0.0, dropout_rate=0.0, byzantine_frac=0.0)
     k = 12
-    cohort = np.arange(0, 40, 40 // k)[:k]
-    cx, cy = pop.cohort_data(cohort)
-    cohort_idx = jnp.asarray(cohort)
+    cohort_idx = np.arange(0, 40, 40 // k)[:k]
 
     # discover the round's labels (mask-independent), then craft an arrival
     # mask that leaves one whole cluster empty
     probe = _sim(pop, mesh_shards=8, rounds=1)
     _, probe_out = probe.engine.sync_step(
-        probe.arena.data, cohort_idx, cx, cy, jnp.ones((k,), jnp.float32))
+        probe.arena.data, cohort_idx, *probe.step_data, np.ones(k, np.float32))
     labels = np.asarray(probe_out.labels)
     mask = labels != labels[0]
     assert mask.any() and not mask.all()
 
     a = _sim(pop, mesh_shards=8, rounds=1)
     b = _sim(pop, mesh_shards=1, rounds=1)
-    arrived_w = jnp.asarray(mask, jnp.float32)
-    da, oa = a.engine.sync_step(a.arena.data, cohort_idx, cx, cy, arrived_w)
-    db, ob = b.engine.sync_step(b.arena.data, cohort_idx, cx, cy, arrived_w)
+    arrived_w = mask.astype(np.float32)
+    da, oa = a.engine.sync_step(a.arena.data, cohort_idx, *a.step_data,
+                                arrived_w)
+    db, ob = b.engine.sync_step(b.arena.data, cohort_idx, *b.step_data,
+                                arrived_w)
     np.testing.assert_array_equal(np.asarray(oa.labels), np.asarray(ob.labels))
     np.testing.assert_array_equal(
         np.asarray(oa.new_rows).view(np.uint32),
@@ -283,6 +283,40 @@ def test_sharded_zero_arrival_cluster_matches_single_device():
     np.testing.assert_array_equal(
         np.asarray(da[: a.arena.n_clients]).view(np.uint32),
         np.asarray(db).view(np.uint32))
+
+
+@mesh8
+def test_sharded_steps_gather_the_cohort_data_in_step():
+    """On the mesh the population's data is row-sharded like the arena and
+    the step gathers the cohort's slice itself: the outputs equal, bit for
+    bit, a single-device step given ``pop.cohort_data`` through an identity
+    index over the cohort's own rows.  The mesh's FedBuff step gives the
+    same bits on the host-gathered flush data and the snapshot rows as a
+    sequence as on ``pop.cohort_data`` and one stacked array."""
+    pop = _pop(n=40, straggler_frac=0.0, dropout_rate=0.0, byzantine_frac=0.0)
+    a = _sim(pop, mesh_shards=8, rounds=1)
+    b = _sim(pop, mesh_shards=1, rounds=1)
+    assert len(a.step_data[0].sharding.device_set) == 8
+    k = 12
+    cohort = np.random.default_rng(0).choice(40, k, replace=False)
+    arrived = (np.arange(k) % 5 != 2).astype(np.float32)
+    own_rows = jnp.asarray(np.asarray(b.arena.data)[cohort])
+    da, oa = a.engine.sync_step(a.arena.data, cohort, *a.step_data, arrived)
+    db, ob = b.engine.sync_step(own_rows, np.arange(k),
+                                *pop.cohort_data(cohort), arrived)
+    # the Pearson matrix (CACC's input only) is not part of the mesh replay
+    # contract: its combine rounds differently on the mesh, as before
+    for name in ("labels", "residues", "mean_loss", "new_rows"):
+        assert np.asarray(getattr(oa, name)).tobytes() \
+            == np.asarray(getattr(ob, name)).tobytes(), name
+    assert np.asarray(da)[cohort].tobytes() == np.asarray(db).tobytes()
+
+    clients = cohort[:5]
+    rows = [b.arena.data[i] for i in (0, 0, 1, 0, 2)]
+    got = a.engine.async_step(rows, *a.flush_data(clients))
+    want = a.engine.async_step(jnp.stack(rows), *pop.cohort_data(clients))
+    for x, y in zip(got, want):
+        assert np.asarray(x).tobytes() == np.asarray(y).tobytes()
 
 
 @mesh8

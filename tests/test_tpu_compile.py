@@ -82,14 +82,14 @@ def test_sync_step_compiles_for_one_chip_with_kernel(one_chip, monkeypatch,
     pop = ClientPopulation.from_spec(spec.population_spec())
     sim = SimulatedFederation(pop, spec)
     k = 100
-    cx, cy = pop.cohort_data(np.arange(k))
+    data_x, data_y = sim.step_data
 
     def on_chip(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
     args = (on_chip(sim.arena.data.shape, jnp.float32),
-            on_chip((k,), jnp.int32), on_chip(cx.shape, cx.dtype),
-            on_chip(cy.shape, cy.dtype), on_chip((k,), jnp.float32))
+            on_chip((k,), jnp.int32), on_chip(data_x.shape, data_x.dtype),
+            on_chip(data_y.shape, data_y.dtype), on_chip((k,), jnp.float32))
     hlo = sim.engine.lower_entry("sync_step", *args).compile().as_text()
     assert "tpu_custom_call" in hlo
 
