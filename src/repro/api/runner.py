@@ -114,8 +114,10 @@ def run(spec: ExperimentSpec, population: ClientPopulation | None = None,
     uninterrupted run's.
     """
     if population is None:
-        population = ClientPopulation.from_spec(spec.population_spec())
-    elif population.spec != spec.population_spec():
+        population = ClientPopulation.from_spec(spec.population_spec(),
+                                                spec.token_spec())
+    elif population.spec != spec.population_spec() \
+            or population.tokens != spec.token_spec():
         raise ValueError(
             "supplied population was built from a different PopulationSpec "
             "than spec.data/spec.seed would rebuild — the manifest's "

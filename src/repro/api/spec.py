@@ -67,9 +67,21 @@ class DataSpec:
     base_latency: float = 10.0
     latency_sigma: float = 0.25
     psi: int = 32                     # probe-batch size for PAA
+    # token data (dataset "markov-topics", repro.data.lm): rows of seq_len
+    # inputs with their next-token targets, over a vocab_size vocabulary;
+    # beta mixes n_topics topics per client; PAA probes psi rows of
+    # probe_len tokens
+    seq_len: int = 0
+    vocab_size: int = 0
+    n_topics: int = 8
+    probe_len: int = 256
 
     def __post_init__(self):
         _check(self.n_clients >= 1, f"n_clients must be >= 1, got {self.n_clients}")
+        if self.dataset == "markov-topics":
+            for f in ("seq_len", "vocab_size", "n_topics", "probe_len"):
+                _check(getattr(self, f) >= 1,
+                       f"{f} must be >= 1 for token data, got {getattr(self, f)}")
         for f in ("n_batches", "batch_size", "psi"):
             _check(getattr(self, f) >= 1, f"{f} must be >= 1, got {getattr(self, f)}")
         _check(self.beta > 0, f"beta must be > 0, got {self.beta}")
@@ -96,6 +108,11 @@ class TrainSpec:
     mode: str = "sync"                # "sync" | "async"
     hidden: tuple[int, ...] = (64,)   # MLP widths of the trained model
     rep_dim: int = 32
+    # the trained model: "mlp" (the classifier above), or an architecture
+    # of repro.configs.FEDERATED_ARCHS trained as LoRA adapters over a
+    # frozen base, its config cut or changed by model_overrides
+    model: str = "mlp"
+    model_overrides: Mapping[str, Any] = field(default_factory=dict)
 
     def __post_init__(self):
         # strategy membership is checked lazily against the registry so the
@@ -117,6 +134,10 @@ class TrainSpec:
         _check(self.rep_dim >= 1, f"rep_dim must be >= 1, got {self.rep_dim}")
         _check(len(self.hidden) >= 1 and all(h >= 1 for h in self.hidden),
                f"hidden must be a non-empty tuple of widths, got {self.hidden!r}")
+        from repro.configs import FEDERATED_ARCHS
+        _check(self.model == "mlp" or self.model in FEDERATED_ARCHS,
+               f"unknown model {self.model!r}; options: "
+               f"{['mlp', *sorted(FEDERATED_ARCHS)]}")
 
 
 @dataclass(frozen=True)
@@ -242,7 +263,17 @@ class ExperimentSpec:
         """The ``repro.sim.PopulationSpec`` this experiment's population uses
         (seeded with the experiment seed)."""
         from repro.sim.population import PopulationSpec
-        return PopulationSpec(**dataclasses.asdict(self.data), seed=self.seed)
+        keys = PopulationSpec.__dataclass_fields__.keys() - {"seed"}
+        d = dataclasses.asdict(self.data)
+        return PopulationSpec(**{k: d[k] for k in keys}, seed=self.seed)
+
+    def token_spec(self):
+        """The ``repro.sim.population.TokenSpec`` of token data, or None."""
+        if self.data.dataset != "markov-topics":
+            return None
+        from repro.sim.population import TokenSpec
+        d = self.data
+        return TokenSpec(d.seq_len, d.vocab_size, d.n_topics, d.probe_len)
 
     def sim_config(self):
         """Flat legacy view (``repro.sim.SimConfig``) consumed by the round
@@ -259,7 +290,8 @@ class ExperimentSpec:
             total_reward=c.total_reward, rho=c.rho,
             initial_stake=c.initial_stake, eval_every=e.every,
             eval_clients=e.clients, eval_examples=e.examples,
-            hidden=tuple(t.hidden), rep_dim=t.rep_dim, engine=self.engine,
+            hidden=tuple(t.hidden), rep_dim=t.rep_dim, model=t.model,
+            model_overrides=dict(t.model_overrides), engine=self.engine,
             mesh_shards=self.mesh.shards, mesh_cohort=self.mesh.cohort,
             seed=self.seed)
 
@@ -278,6 +310,7 @@ class ExperimentSpec:
         d = dataclasses.asdict(self)
         d["train"]["hidden"] = list(self.train.hidden)
         d["train"]["strategy_params"] = dict(self.train.strategy_params)
+        d["train"]["model_overrides"] = dict(self.train.model_overrides)
         d["mesh"]["xla_flags"] = list(self.mesh.xla_flags)
         for f in _FAULT_TUPLE_FIELDS:
             d["faults"][f] = list(getattr(self.faults, f))

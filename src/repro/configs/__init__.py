@@ -1,5 +1,6 @@
-"""Architecture registry: the 10 assigned architectures (+ the paper's own
-CIFAR-scale classifier via repro.models.classifier)."""
+"""Architecture registry: the 10 assigned architectures and AI21-Jamba2-3B,
+the one the federation trains (+ the paper's own CIFAR-scale classifier via
+repro.models.classifier)."""
 from __future__ import annotations
 
 from repro.configs import (
@@ -8,6 +9,7 @@ from repro.configs import (
     grok_1_314b,
     h2o_danube_3_4b,
     internvl2_2b,
+    jamba2_3b,
     jamba_1_5_large_398b,
     llama4_maverick_400b_a17b,
     minitron_8b,
@@ -30,8 +32,13 @@ ARCHS: dict[str, ArchConfig] = {
         rwkv6_3b,
         whisper_large_v3,
         minitron_8b,
+        jamba2_3b,
     )
 }
+
+#: architectures the federation can train (LoRA over a frozen base): name ->
+#: builder of the config from overrides (``TrainSpec.model_overrides``)
+FEDERATED_ARCHS = {"jamba2-3b": jamba2_3b.make_config}
 
 # long_500k applicability (see DESIGN.md §5): sub-quadratic decode only.
 LONG_CONTEXT_OK = {
@@ -45,6 +52,7 @@ LONG_CONTEXT_OK = {
     "rwkv6-3b": True,             # recurrent, O(1) state
     "whisper-large-v3": False,    # enc-dec, 448-token decoder spec
     "minitron-8b": False,         # pure full attention
+    "jamba2-3b": True,            # mamba-dominant hybrid
 }
 
 

@@ -1,6 +1,7 @@
 """jamba-1.5-large-398b [hybrid] — 72L d_model=8192 64H (GQA kv=8,
 head_dim=128) d_ff=24576 vocab=65536; Mamba:attention 7:1 interleave
-(one attention layer per 8-layer block), MoE 16e top-2 every other layer.
+(one attention layer per 8-layer block), MoE 16e top-2 every other layer,
+RMSNorms on the Mamba Δ, B and C.
 [arXiv:2403.19887]
 """
 from repro.models.transformer import ArchConfig, LayerSpec
@@ -28,6 +29,7 @@ CONFIG = ArchConfig(
     mamba_d_state=16,
     mamba_d_conv=4,
     mamba_expand=2,
+    mamba_inner_norms=True,
     tie_embeddings=True,
     sharding_mode="fsdp_tp",
     source="arXiv:2403.19887",

@@ -57,16 +57,30 @@ from repro.core.aggregation import (
 from repro.core.pearson import pearson_affinity, pearson_matrix
 from repro.core.prototypes import classwise_prototypes, client_prototypes
 from repro.core.spectral import spectral_cluster
+from repro.models.layers import TiedHead
 from repro.utils.tree import tree_sq_norm, tree_sub
 
 Pytree = Any
 
 
 class ModelBundle(NamedTuple):
-    """The model as the FL layer sees it (architecture-agnostic)."""
+    """The model as the FL layer sees it (architecture-agnostic).
+
+    ``params`` are what a client trains (one arena row).  A model with a
+    frozen base every client shares (a LoRA-adapted LM) carries it in
+    ``base``, and ``bind(base)`` returns the bundle whose functions close
+    over that base: the round engine passes the base into its compiled
+    entries as an argument and binds it there, so it is never a constant
+    of an executable."""
     apply_fn: Callable[[Pytree, jax.Array], jax.Array]   # params, x -> logits
     embed_fn: Callable[[Pytree, jax.Array], jax.Array]   # params, x -> representations
     num_classes: int
+    base: Pytree = None
+    bind: Callable[[Pytree], "ModelBundle"] | None = None
+    eval_metric: str = "accuracy"     # "accuracy" | "loss" (next-token LM)
+
+    def with_base(self, base: Pytree) -> "ModelBundle":
+        return self if self.bind is None else self.bind(base)
 
 
 class AggOut(NamedTuple):
@@ -106,9 +120,48 @@ class Strategy(NamedTuple):
         [Pytree, Any, jax.Array, int], "CohortAggOut"] | None = None
 
 
-def _xent(logits: jax.Array, y: jax.Array) -> jax.Array:
+#: tokens scored per block of a tied-head LM loss
+LOSS_BLOCK = 128
+
+
+def _xent(logits, y: jax.Array) -> jax.Array:
+    """Mean cross-entropy of ``(..., C)`` logits against ``(...)`` labels;
+    for a language model, ``(B, S, V)`` logits against next-token targets
+    ``(B, S)``, or its :class:`~repro.models.layers.TiedHead`, scored a
+    block of tokens at a time."""
+    if isinstance(logits, TiedHead):
+        return _tied_head_xent(logits.hidden, logits.table, y)
     logp = jax.nn.log_softmax(logits, axis=-1)
     return -jnp.mean(jnp.take_along_axis(logp, y[..., None], axis=-1))
+
+
+@jax.named_scope("lm_loss")
+def _tied_head_xent(hidden: jax.Array, table: jax.Array, y: jax.Array,
+                    block: int = LOSS_BLOCK) -> jax.Array:
+    """Next-token loss of hidden states ``(..., D)`` scored against the tied
+    head ``table (V, D)``, ``block`` tokens at a time: each block's logits
+    are made, reduced to its tokens' losses and dropped, and remade in the
+    backward pass, so no more than one block of ``(block, V)`` logits and
+    its gradient exist at once."""
+    d = hidden.shape[-1]
+    h, t = hidden.reshape(-1, d), y.reshape(-1)
+    n_tok = h.shape[0]
+    blk = min(block, n_tok)
+    n = -(-n_tok // blk)
+    pad = n * blk - n_tok
+    if pad:
+        h = jnp.pad(h, ((0, pad), (0, 0)))
+        t = jnp.pad(t, (0, pad))
+
+    @jax.checkpoint
+    def block_nll(hb, tb):
+        z = hb @ table.T
+        return jax.nn.logsumexp(z, axis=-1) \
+            - jnp.take_along_axis(z, tb[:, None], axis=-1)[:, 0]
+
+    nll = jax.lax.map(lambda a: block_nll(*a),
+                      (h.reshape(n, blk, d), t.reshape(n, blk))).reshape(-1)
+    return jnp.sum(nll[:n_tok]) / n_tok
 
 
 def _flatten_batches(cx: jax.Array, cy: jax.Array) -> tuple[jax.Array, jax.Array]:

@@ -35,6 +35,14 @@ The async (FedBuff) path has two entries: ``async_step`` trains the
 flushed buffer and fingerprints it, and ``async_merge`` is the whole
 staleness-weighted merge over flat ``(k, N)`` rows in one program.
 
+A model with a frozen base every client shares (a LoRA-adapted LM, whose
+arena rows hold only the adapters) passes that base as the last argument of
+``sync_step``, ``eval_cohort`` and ``eval_population``; the engine's
+``bind`` turns it into the strategy and forward inside the trace, so the
+base is an argument of the executables, never a constant folded into them,
+and one copy serves the whole cohort.  Such a model's eval is its mean
+held-out next-token loss, client by client.
+
 Evaluation entries are split so each compiles exactly once: a fixed-shape
 mask-weighted cohort eval (round metric), a single-row global eval (async),
 and a population eval with its own entry (final metric) so the final pass
@@ -73,7 +81,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.aggregation import staleness_weight, weighted_delta_mean
-from repro.core.baselines import barrier_combine_inputs
+from repro.core.baselines import _xent, barrier_combine_inputs
 from repro.core.fl import local_train
 from repro.kernels.fingerprint import (
     fingerprint_rows,
@@ -132,6 +140,8 @@ class RoundEngine:
         stacked_apply_fn: Callable | None = None,
         sharding=None,                  # client-axis NamedSharding (mesh mode)
         cohort_mode: str = "sharded",   # mesh mode: "sharded" | "replicated"
+        bind: Callable | None = None,   # base -> (strategy, apply_fn)
+        eval_metric: str = "accuracy",  # "accuracy" | "loss"
     ):
         if strategy.aggregate_cohort is None:
             raise ValueError(
@@ -142,6 +152,9 @@ class RoundEngine:
             raise ValueError(
                 f"cohort_mode must be one of {COHORT_MODES}, "
                 f"got {cohort_mode!r}")
+        if eval_metric not in ("accuracy", "loss"):
+            raise ValueError(f"eval_metric must be 'accuracy' or 'loss', "
+                             f"got {eval_metric!r}")
         self.layout = layout
         self.n_clusters = n_clusters
         self.strategy_name = strategy.name
@@ -205,11 +218,23 @@ class RoundEngine:
         def _cohort_pad(k: int) -> int:
             return (-(-k // pad_mult) * pad_mult) - k if sharded_cohort else 0
 
-        def _client_accs(params, ex, ey):
-            """(m,) per-client accuracy on the shared eval batch.  Uses the
-            model's width-concatenated stacked forward when available — the
-            vmap form broadcasts the shared batch into a batched dot that
-            XLA CPU lowers ~2.5× slower at 100-client cohorts."""
+        def _bound(base):
+            """(strategy, forward) over ``base``, the frozen weights the
+            entry received as an argument (None: the model is all rows)."""
+            if bind is None:
+                return strategy, apply_fn
+            return bind(base)
+
+        def _client_accs(params, ex, ey, apply_fn=apply_fn):
+            """(m,) per-client accuracy on the shared eval batch, or for an
+            LM its mean next-token loss (client by client, so one client's
+            activations live at a time).  Uses the model's
+            width-concatenated stacked forward when available — the vmap
+            form broadcasts the shared batch into a batched dot that XLA CPU
+            lowers ~2.5× slower at 100-client cohorts."""
+            if eval_metric == "loss":
+                return jax.lax.map(lambda p: _xent(apply_fn(p, ex), ey),
+                                   params)
             if stacked_apply_fn is not None:
                 logits = stacked_apply_fn(params, ex)          # (m, B, C)
             else:
@@ -217,7 +242,7 @@ class RoundEngine:
             hits = (jnp.argmax(logits, axis=-1) == ey[None, :])
             return jnp.mean(hits.astype(jnp.float32), axis=1)
 
-        def _train(cohort_params, cx, cy, extras):
+        def _train(strategy, cohort_params, cx, cy, extras):
             opt_state = jax.vmap(opt.init)(cohort_params)
             res = local_train(strategy.local_loss, opt, cohort_params,
                               opt_state, cx, cy, extras, local_epochs,
@@ -230,17 +255,19 @@ class RoundEngine:
             # repro.core.baselines.barrier_combine_inputs)
             return jax.lax.optimization_barrier(res)
 
-        def _pad_extras(extras, pad):
+        def _pad_extras(strategy, extras, pad):
             """Per-client server payloads get zero padding slots; a shared
             payload (no client axis) ships as-is."""
             if strategy.shared_extras or pad == 0:
                 return extras
             return jax.tree.map(lambda e: _pad0(e, pad), extras)
 
-        def _sync_step(arena, cohort_idx, data_x, data_y, arrived):
+        def _sync_step(arena, cohort_idx, data_x, data_y, arrived, base=None):
             """``data_x``/``data_y``: the whole population's train data,
             placed like the arena's rows; the cohort's slice is gathered
-            here with the rows' own index."""
+            here with the rows' own index.  ``base``: the model's frozen
+            weights, if it has any."""
+            strategy, _ = _bound(base)
             k = cohort_idx.shape[0]
             pad = _cohort_pad(k)
             if sharded_cohort:
@@ -267,11 +294,12 @@ class RoundEngine:
                 # rewriting it into partial sums + all-reduce (ULP flips)
                 with jax.named_scope("local_train"):
                     rows_real = _rep(rows[:k])
-                    extras = _pad_extras(jax.tree.map(
+                    extras = _pad_extras(strategy, jax.tree.map(
                         _rep, strategy.round_extras(
                             layout.unflatten(rows_real), _rep(cx),
                             _rep(cy))), pad)
-                    res = _train(layout.unflatten(rows), cx_p, cy_p, extras)
+                    res = _train(strategy, layout.unflatten(rows), cx_p,
+                                 cy_p, extras)
                 # shard-local per-slot partial (BFLN: prototypes); only this
                 # small matrix replicates into the deterministic combine —
                 # whose cohort-axis reductions are fixed-order trees, so the
@@ -319,7 +347,8 @@ class RoundEngine:
                 with jax.named_scope("local_train"):
                     extras = strategy.round_extras(layout.unflatten(rows),
                                                    cx, cy)
-                    res = _train(layout.unflatten(rows), cx, cy, extras)
+                    res = _train(strategy, layout.unflatten(rows), cx, cy,
+                                 extras)
                 # aggregation over ALL cohort slots (stragglers burn local
                 # compute too); only the aggregation weights honour the
                 # arrival mask
@@ -363,11 +392,12 @@ class RoundEngine:
                 # the cohort-reducing server payload, and the latter must
                 # keep the single-device op sequence
                 with jax.named_scope("local_train"):
-                    extras = _pad_extras(jax.tree.map(
+                    extras = _pad_extras(strategy, jax.tree.map(
                         _rep, strategy.round_extras(
                             layout.unflatten(_rep(base_rows)), _rep(cx),
                             _rep(cy))), pad)
-                    res = _train(layout.unflatten(rows), cx_p, cy_p, extras)
+                    res = _train(strategy, layout.unflatten(rows), cx_p,
+                                 cy_p, extras)
                 local_rows_p = layout.flatten(res.params)
                 residues = _fingerprint(local_rows_p)[:k]
                 local_rows = _rep(local_rows_p[:k])
@@ -376,7 +406,8 @@ class RoundEngine:
             with jax.named_scope("local_train"):
                 extras = strategy.round_extras(layout.unflatten(base_rows),
                                                cx, cy)
-                res = _train(layout.unflatten(base_rows), cx, cy, extras)
+                res = _train(strategy, layout.unflatten(base_rows), cx, cy,
+                             extras)
             local_rows = layout.flatten(res.params)
             residues = _fingerprint(_rep(local_rows))
             return local_rows, residues, jnp.mean(res.mean_loss)
@@ -398,7 +429,7 @@ class RoundEngine:
             step = _unfused(server_lr * merged, server_lr)
             return _rep(global_row) + step, w
 
-        def _eval_cohort(cohort_rows, arrived, labels, ex, ey):
+        def _eval_cohort(cohort_rows, arrived, labels, ex, ey, base=None):
             """Fixed-shape mask-weighted cohort accuracy (the jnp-generic
             reference is ``repro.core.fl.masked_global_evaluate``).  Takes
             the cohort's (k, N) rows — NOT the arena — so a deferred eval
@@ -406,13 +437,16 @@ class RoundEngine:
             the per-client forwards shard over the cohort axis; the scalar
             combine runs on the replicated (k,) accuracies with the exact
             single-device op sequence."""
+            _, fwd = _bound(base)
             k = cohort_rows.shape[0]
             pad = _cohort_pad(k)
             if sharded_cohort:
                 rows = _csh(_pad0(cohort_rows, pad))
-                accs = _rep(_client_accs(layout.unflatten(rows), ex, ey)[:k])
+                accs = _rep(_client_accs(layout.unflatten(rows), ex, ey,
+                                         fwd)[:k])
             else:
-                accs = _client_accs(layout.unflatten(cohort_rows), ex, ey)
+                accs = _client_accs(layout.unflatten(cohort_rows), ex, ey,
+                                    fwd)
             w = arrived.astype(jnp.float32)
             acc = jnp.sum(accs * w) / jnp.maximum(jnp.sum(w), 1.0)
             onehot = jax.nn.one_hot(labels, n_clusters, dtype=jnp.float32) \
@@ -425,7 +459,8 @@ class RoundEngine:
         def _eval_global(global_row, ex, ey):
             return _client_accs(layout.unflatten(global_row[None]), ex, ey)[0]
 
-        def _eval_population(arena, ids, ex, ey):
+        def _eval_population(arena, ids, ex, ey, base=None):
+            _, fwd = _bound(base)
             n = ids.shape[0]
             pad = _cohort_pad(n)
             if sharded_cohort:
@@ -434,10 +469,11 @@ class RoundEngine:
                 ids_p = jnp.concatenate(
                     [ids, jnp.zeros((pad,), ids.dtype)]) if pad else ids
                 rows = _csh(arena[ids_p])
-                accs = _rep(_client_accs(layout.unflatten(rows), ex, ey)[:n])
+                accs = _rep(_client_accs(layout.unflatten(rows), ex, ey,
+                                         fwd)[:n])
                 return jnp.mean(accs)
             rows = _rep(arena[ids])       # replicate only the sampled rows
-            return jnp.mean(_client_accs(layout.unflatten(rows), ex, ey))
+            return jnp.mean(_client_accs(layout.unflatten(rows), ex, ey, fwd))
 
         self.sync_step = jax.jit(_sync_step, donate_argnums=(0,))
         self.async_step = jax.jit(_async_step)

@@ -7,5 +7,6 @@
 | fingerprint       | per-client model commitment digests (chain)      | ref.fingerprint_ref   |
 | flash_attention   | causal/SWA GQA attention, online softmax         | ref.attention_ref     |
 | rwkv6_scan        | RWKV6 wkv recurrence, data-dependent decay       | ref.rwkv6_scan_ref    |
+| selective_scan    | Mamba-1 selective scan, forward and backward     | mamba.chunked_scan    |
 """
 from repro.kernels import ops, ref  # noqa: F401

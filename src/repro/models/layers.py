@@ -7,6 +7,8 @@ model axis still shard cleanly on the fused dim.
 """
 from __future__ import annotations
 
+from typing import NamedTuple
+
 import jax
 import jax.numpy as jnp
 
@@ -37,6 +39,24 @@ def init_norm(kind: str, dim: int, dtype) -> dict:
     if kind == "rmsnorm":
         return {"scale": jnp.zeros((dim,), dtype)}
     return {"scale": jnp.ones((dim,), dtype), "bias": jnp.zeros((dim,), dtype)}
+
+
+class TiedHead(NamedTuple):
+    """A language model's output before its tied head: final hidden states
+    ``(..., S, D)`` and the embedding table ``(V, D)`` they are scored
+    against.  A loss that takes this instead of ``(..., S, V)`` logits can
+    score the tokens a block at a time (``repro.core.baselines._xent``)."""
+    hidden: jax.Array
+    table: jax.Array
+
+
+def dense(x: jax.Array, w) -> jax.Array:
+    """``x @ w`` for a plain weight or a LoRA-adapted one
+    (:class:`repro.models.lora.LoraWeight`)."""
+    from repro.models.lora import LoraWeight, lora_dense
+    if isinstance(w, LoraWeight):
+        return lora_dense(x, w)
+    return x @ w
 
 
 def dense_init(key: jax.Array, in_dim: int, out_dim: int, dtype) -> jax.Array:

@@ -25,6 +25,7 @@ from repro.models import attention as attn
 from repro.models import mamba as mb
 from repro.models import rwkv as rk
 from repro.models.layers import (
+    dense,
     dense_init,
     ffn_apply,
     ffn_init,
@@ -80,6 +81,7 @@ class ArchConfig:
     mamba_d_state: int = 16
     mamba_d_conv: int = 4
     mamba_expand: int = 2
+    mamba_inner_norms: bool = False   # Jamba's RMSNorms on Δ, B and C
     # RWKV
     rwkv_head_dim: int = 64
     rwkv_lora_rank: int = 64
@@ -90,7 +92,10 @@ class ArchConfig:
     rope_theta: float = 10000.0
     tie_embeddings: bool = True
     param_dtype: str = "bfloat16"
+    act_dtype: str = ""          # activations ("" = param_dtype)
+    scale_embed: bool = True     # multiply embeddings by sqrt(d_model)
     remat: bool = True
+    remat_layers: bool = False   # rematerialise each layer, not each period
     sharding_mode: str = "tp"    # tp | fsdp_tp | ep_tp (expert-parallel MoE)
     swa_skip: bool = False       # skip fully-masked attention chunks (§Perf)
     # §Perf: pin attention activations to batch-sharded / model-replicated.
@@ -120,6 +125,10 @@ class ArchConfig:
     def padded_vocab(self) -> int:
         m = self.vocab_pad_multiple
         return -(-self.vocab_size // m) * m
+
+    @property
+    def activation_dtype(self):
+        return jnp.dtype(self.act_dtype or self.param_dtype)
 
     @property
     def mamba_d_inner(self) -> int:
@@ -196,7 +205,8 @@ def _init_layer(cfg: ArchConfig, spec: LayerSpec, key: jax.Array) -> dict:
     elif spec.mixer == "mamba":
         p["norm1"] = init_norm(cfg.norm, D, dt)
         p["mamba"] = mb.mamba_init(ks[0], D, cfg.mamba_d_inner, cfg.mamba_d_state,
-                                   cfg.mamba_d_conv, cfg.mamba_dt_rank, dt)
+                                   cfg.mamba_d_conv, cfg.mamba_dt_rank, dt,
+                                   inner_norms=cfg.mamba_inner_norms)
     elif spec.mixer == "rwkv":
         p["norm1"] = init_norm(cfg.norm, D, dt)
         p["time_mix"] = rk.rwkv_time_mix_init(
@@ -274,13 +284,14 @@ def _constrain_attn(cfg: ArchConfig, *ts):
     return out if len(out) > 1 else out[0]
 
 
+@jax.named_scope("attention")
 def _attn_sublayer(cfg: ArchConfig, spec: LayerSpec, p: dict, h: jax.Array,
                    pos_ids: jax.Array, enc_out: jax.Array | None) -> jax.Array:
     B, S, D = h.shape
     x = norm(cfg.norm, h, p["norm1"])
-    q = (x @ p["q"]).reshape(B, S, cfg.n_heads, cfg.head_dim)
-    k = (x @ p["k"]).reshape(B, S, cfg.n_kv_heads, cfg.head_dim)
-    v = (x @ p["v"]).reshape(B, S, cfg.n_kv_heads, cfg.head_dim)
+    q = dense(x, p["q"]).reshape(B, S, cfg.n_heads, cfg.head_dim)
+    k = dense(x, p["k"]).reshape(B, S, cfg.n_kv_heads, cfg.head_dim)
+    v = dense(x, p["v"]).reshape(B, S, cfg.n_kv_heads, cfg.head_dim)
     q, k, v = _constrain_attn(cfg, q, k, v)
     if cfg.qk_norm and "q_norm" in p:
         q = rms_norm(q, p["q_norm"]["scale"])
@@ -295,7 +306,7 @@ def _attn_sublayer(cfg: ArchConfig, spec: LayerSpec, p: dict, h: jax.Array,
         out = attn.attend_full(q, k, v, causal=spec.causal, window=spec.window,
                                q_pos=pos_ids[0], k_pos=pos_ids[0])
     out = _constrain_attn(cfg, out)
-    h = h + out.reshape(B, S, -1) @ p["o"]
+    h = h + dense(out.reshape(B, S, -1), p["o"])
 
     if spec.cross_attn and enc_out is not None:
         xc = norm(cfg.norm, h, p["norm_c"])
@@ -366,19 +377,24 @@ def backbone(cfg: ArchConfig, params: Pytree, h: jax.Array,
              ) -> tuple[jax.Array, jax.Array]:
     """Apply all layers to hidden states h (B, S, D). Returns (h, moe_aux)."""
 
+    def layer_fn(spec):
+        f = lambda p, h: _apply_layer(cfg, spec, p, h, pos_ids, enc_out)  # noqa: E731
+        return jax.checkpoint(f) if cfg.remat and cfg.remat_layers else f
+
     def period_body(carry, period_params):
         h, aux = carry
         for i, spec in enumerate(cfg.pattern):
-            h, a = _apply_layer(cfg, spec, period_params[i], h, pos_ids, enc_out)
+            h, a = layer_fn(spec)(period_params[i], h)
             aux = aux + a
         return (h, aux), None
 
-    body = jax.checkpoint(period_body) if cfg.remat else period_body
+    body = jax.checkpoint(period_body) if cfg.remat and not cfg.remat_layers \
+        else period_body
     aux = jnp.zeros((), jnp.float32)
     if cfg.n_periods > 0:
         (h, aux), _ = jax.lax.scan(body, (h, aux), params["layers"])
     for i, spec in enumerate(cfg.remainder):
-        h, a = _apply_layer(cfg, spec, params["rem_layers"][i], h, pos_ids, enc_out)
+        h, a = layer_fn(spec)(params["rem_layers"][i], h)
         aux = aux + a
     return h, aux
 
@@ -407,10 +423,11 @@ def forward(cfg: ArchConfig, params: Pytree, tokens: jax.Array | None = None,
     """Full forward: returns (logits (B,S,V), final hidden (B,S,D), moe_aux)."""
     if embeds is None:
         assert tokens is not None
-        h = params["embed"].astype(cfg.dtype)[tokens]
-        h = h * jnp.asarray(cfg.d_model ** 0.5, h.dtype)
+        h = params["embed"][tokens].astype(cfg.activation_dtype)
+        if cfg.scale_embed:
+            h = h * jnp.asarray(cfg.d_model ** 0.5, h.dtype)
     else:
-        h = embeds.astype(cfg.dtype)
+        h = embeds.astype(cfg.activation_dtype)
     B, S = h.shape[:2]
     pos_ids = jnp.broadcast_to(jnp.arange(S)[None], (B, S))
     if cfg.abs_pos:
@@ -422,6 +439,19 @@ def forward(cfg: ArchConfig, params: Pytree, tokens: jax.Array | None = None,
     h = norm(cfg.norm, h, params["final_norm"])
     logits = unembed(cfg, params, h)
     return logits, h, aux
+
+
+def hidden_states(cfg: ArchConfig, params: Pytree, tokens: jax.Array
+                  ) -> jax.Array:
+    """Token-only decoder: tokens (B, S) -> final-norm hidden states
+    (B, S, D), the input of the (tied) head."""
+    h = params["embed"][tokens].astype(cfg.activation_dtype)
+    if cfg.scale_embed:
+        h = h * jnp.asarray(cfg.d_model ** 0.5, h.dtype)
+    B, S = tokens.shape
+    pos_ids = jnp.broadcast_to(jnp.arange(S)[None], (B, S))
+    h, _ = backbone(cfg, params, h, pos_ids)
+    return norm(cfg.norm, h, params["final_norm"])
 
 
 def unembed(cfg: ArchConfig, params: Pytree, h: jax.Array) -> jax.Array:

@@ -62,6 +62,8 @@ COUNTER_NAMES = frozenset({
     "serve.verifications",
     # calls of the round engine's compiled entries from the round loop
     "engine.sync_step", "engine.async_step", "engine.async_merge",
+    # tokens the sync step trained on (every cohort slot; token models only)
+    "engine.tokens",
 }) | (FAULT_EVENT_NAMES - {"fault.commit_delivered_late"})
 
 # --- gauges: last-written values (recorder.set_gauge) --------------------- #
@@ -76,6 +78,17 @@ SERIES_NAMES = frozenset({
     "async.staleness", "async.staleness_weight", "async.staleness_mean",
     "ledger.paid", "ledger.fees", "ledger.burned",
     "serve.latency", "serve.batch_size",
+})
+
+# --- named scopes: jax.named_scope names inside the compiled entries ----- #
+# (not recorder names: they label device ops in the profiler trace, where
+# bench/ readers find them; docs/TRACE_SCHEMA.md lists them beside spans)
+SCOPE_NAMES = frozenset({
+    "gather", "local_train", "cohort_combine", "paa", "cluster_means",
+    "fingerprint", "scatter_back",
+    # inside the model (repro.models): the selective scan, attention, LoRA
+    # products, the blocked next-token loss
+    "mamba_scan", "attention", "lora", "lm_loss",
 })
 
 # Dynamic families: a recorder call may build its name with an f-string as

@@ -318,6 +318,11 @@ def snapshot(source, *, publish: bool = True, verify: bool = True,
         raise ValueError(
             "snapshot() needs a finished run: pass the ExperimentResult "
             "returned by repro.api.run(spec) (or the SimulatedFederation)")
+    if sim.mcfg is None:
+        raise ValueError(
+            f"snapshot() serves the classifier MLP; this run trained "
+            f"{sim.model.kind} adapters over a frozen base, and serving a "
+            "generative LM bank (prefill, decode, cache) is not built yet")
     with obs.span("serve.snapshot", cat="serve") as sp:
         n = sim.pop.n_clients
         n_clusters = sim.cfg.n_clusters

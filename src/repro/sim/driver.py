@@ -61,11 +61,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.blockchain import TokenLedger
-from repro.core import FederatedTrainer, ModelBundle, digest_of
+from repro.core import FederatedTrainer, digest_of
 from repro.core.engine import RoundEngine
 from repro.core.fl import global_evaluate, local_train
 from repro.faults import NULL_INJECTOR, FaultInjector
-from repro.models import classifier as clf
 from repro.obs import NULL_RECORDER, FlightRecorder
 from repro.optim import adam
 from repro.runtime.arena import ParamArena, ShardedParamArena
@@ -85,6 +84,12 @@ Pytree = Any
 
 
 _SIMCONFIG_INTERNAL = False    # True while repro.api builds the flat view
+
+
+@functools.partial(jax.jit, static_argnums=(1,))
+def _broadcast_rows(row: jax.Array, n: int) -> jax.Array:
+    """(N,) -> a fresh (n, N) arena with ``row`` in every slot."""
+    return jnp.broadcast_to(row[None], (n, row.shape[0])) + 0.0
 
 
 @dataclass(frozen=True)
@@ -119,6 +124,8 @@ class SimConfig:
     eval_examples: int = 1024         # shared-test sub-sample for evaluation
     hidden: tuple[int, ...] = (64,)
     rep_dim: int = 32
+    model: str = "mlp"                # or a LoRA-trained architecture
+    model_overrides: dict = field(default_factory=dict)
     engine: bool = True               # arena-backed fused round engine
     mesh_shards: int = 1              # >1: shard the arena's client axis over
                                       # a device mesh (engine mode only); on
@@ -177,7 +184,8 @@ class SimConfig:
                 n_clusters=self.n_clusters, local_epochs=self.local_epochs,
                 lr=self.lr, deadline=self.deadline, sampler=self.sampler,
                 mode=self.mode, hidden=tuple(self.hidden),
-                rep_dim=self.rep_dim),
+                rep_dim=self.rep_dim, model=self.model,
+                model_overrides=dict(self.model_overrides)),
             async_=AsyncSpec(
                 buffer_size=self.buffer_size,
                 staleness_alpha=self.staleness_alpha,
@@ -205,7 +213,8 @@ class SimRoundRecord:
     reward_paid: float
     reward_burned: float
     mean_loss: float
-    accuracy: float = float("nan")    # cohort accuracy (sync) / global (async)
+    accuracy: float = float("nan")    # cohort accuracy (sync) / global (async);
+                                      # a token model's held-out next-token loss
     staleness_mean: float = 0.0       # async only
     cluster_accuracy: np.ndarray | None = None   # (C,) engine-mode sync eval
 
@@ -244,8 +253,11 @@ class SimulatedFederation:
     simulator, and the sharded mesh.
     """
 
-    def __init__(self, population: ClientPopulation, config):
+    def __init__(self, population: ClientPopulation, config, weights=None):
+        """``weights``: for a LoRA-trained model, ``(base, row)`` made
+        elsewhere (see :func:`repro.api.setup.build_model`)."""
         from repro.api.registry import build_strategy
+        from repro.api.setup import build_model
         from repro.api.spec import ExperimentSpec
         if isinstance(config, ExperimentSpec):
             self.spec = config
@@ -256,19 +268,29 @@ class SimulatedFederation:
         self.cfg = config
         n = population.n_clients
 
-        mcfg = clf.MLPConfig(in_dim=population.in_dim,
-                             hidden=tuple(config.hidden),
-                             rep_dim=config.rep_dim,
-                             num_classes=population.num_classes)
-        self.mcfg = mcfg    # the serving tier rebuilds forwards from this
-        self.bundle = ModelBundle(functools.partial(clf.apply, mcfg),
-                                  functools.partial(clf.embed, mcfg),
-                                  population.num_classes)
+        if config.model != "mlp":
+            if not config.engine:
+                raise ValueError(
+                    f"{config.model} trains through the round engine only "
+                    "(engine=True); the legacy driver keeps whole models")
+            if config.mode != "sync":
+                raise ValueError(
+                    f"{config.model} federates in sync rounds only: the async "
+                    "(FedBuff) path does not take a frozen base yet")
+        model = build_model(self.spec.train, population, config.seed,
+                            weights=weights)
+        self.model = model
+        self.mcfg = model.mcfg   # the serving tier rebuilds forwards from this
+        self.bundle = model.bundle
         self.opt = adam(config.lr)
-        strat = build_strategy(config.strategy, self.bundle,
-                               probe=population.probe,
-                               n_clusters=config.n_clusters,
-                               **config.strategy_params)
+
+        def make_strategy(bundle):
+            return build_strategy(config.strategy, bundle,
+                                  probe=population.probe,
+                                  n_clusters=config.n_clusters,
+                                  **config.strategy_params)
+
+        strat = make_strategy(self.bundle)
         self.trainer = FederatedTrainer(
             self.bundle, strat, self.opt, local_epochs=config.local_epochs,
             n_clusters=config.n_clusters, total_reward=config.total_reward,
@@ -281,11 +303,15 @@ class SimulatedFederation:
         self.step_data: tuple | None = None   # (cx, cy) where the step reads
         self._host_data: tuple | None = None  # (cx, cy) on the host (async)
         self._eval_data: tuple | None = None
-        self.params = clf.init_stacked(mcfg, jax.random.PRNGKey(config.seed), n)
+        self._params = model.params
         # shared tamper digest for Byzantine commits (built once; chain_round
         # substitutes the digest each freerider *claims*, which never varies)
+        lay = model.layout
+        zeros = [None] * len(lay.order)
+        for pos, i in enumerate(lay.order):
+            zeros[i] = np.zeros(lay.shapes[pos], lay.dtypes[pos])
         self._fake_digest = digest_of(
-            jax.tree.map(jnp.zeros_like, tree_index(self.params, 0)))
+            jax.tree_util.tree_unflatten(lay.treedef, zeros))
         self.last_labels = np.full(n, -1, dtype=np.int64)
         self.sampler = get_sampler(config.sampler)
 
@@ -332,20 +358,33 @@ class SimulatedFederation:
             # state now lives as donated rows of this matrix.  mesh_shards>1
             # row-shards the arena over a client-axis device mesh — each
             # device then holds only n/shards rows of population state
-            if config.mesh_shards > 1:
+            if model.row is not None:
+                if config.mesh_shards > 1:
+                    raise ValueError(f"{model.kind} runs on one device: its "
+                                     "arena is not row-sharded yet")
+                # every client starts from the same adapter row
+                self.arena = ParamArena(model.layout, _broadcast_rows(
+                    model.row, n))
+            elif config.mesh_shards > 1:
                 from repro.launch.mesh import make_client_mesh
                 self.arena = ShardedParamArena.from_stacked(
                     self._params, make_client_mesh(config.mesh_shards))
             else:
                 self.arena = ParamArena.from_stacked(self._params)
             self._params = None
+            bind = None
+            if model.bundle.bind is not None:
+                def bind(base):
+                    b = model.bundle.with_base(base)
+                    return make_strategy(b), b.apply_fn
             self.engine = RoundEngine(
                 self.arena.layout, apply_fn=self.bundle.apply_fn,
                 strategy=strategy, opt=opt,
                 n_clusters=n_clusters, local_epochs=epochs,
-                stacked_apply_fn=functools.partial(clf.apply_stacked, mcfg),
+                stacked_apply_fn=model.stacked_apply_fn,
                 sharding=getattr(self.arena, "sharding", None),
-                cohort_mode=config.mesh_cohort)
+                cohort_mode=config.mesh_cohort, bind=bind,
+                eval_metric=model.bundle.eval_metric)
             # the population's train data, placed once like the arena rows:
             # sync_step gathers each cohort's slice of it on the device
             self.step_data = (self.arena.place_rows(population.cx),
@@ -372,6 +411,9 @@ class SimulatedFederation:
                 self.obs.set_gauge("engine.cohort_bytes", traffic)
         self.trainer.attach_obs(self.obs)
         self.trainer.attach_faults(self.faults)
+        # tokens one client trains on in a round (token data only)
+        self._tokens_per_client = int(np.prod(population.cx.shape[1:])) \
+            * config.local_epochs
 
         # ------- legacy (pre-arena) jitted programs, kept as the oracle ---- #
 
@@ -468,6 +510,12 @@ class SimulatedFederation:
                 return
             self.queue.push(t_retry, ev.DROPOUT, gid, r)
 
+    def _with_base(self, *args) -> tuple:
+        """An engine entry's arguments, with the model's frozen base last
+        where it has one."""
+        base = self.bundle.base
+        return args if base is None else args + (base,)
+
     def _eval_slices(self) -> tuple[jnp.ndarray, jnp.ndarray]:
         if self._eval_data is None:       # sliced once, not every eval round
             self._eval_data = (self.pop.test_x[: self.cfg.eval_examples],
@@ -488,7 +536,7 @@ class SimulatedFederation:
         ex, ey = self._eval_slices()
         if self.engine is not None:
             return float(self.engine.eval_population(
-                self.arena.data, jnp.asarray(ids), ex, ey))
+                *self._with_base(self.arena.data, jnp.asarray(ids), ex, ey)))
         stacked = jax.tree.map(lambda x: x[jnp.asarray(ids)], self._params)
         return float(self._eval_final(stacked, ex, ey))
 
@@ -573,8 +621,13 @@ class SimulatedFederation:
                           shards=self.engine.cohort_shards,
                           cohort_mode=self.engine.cohort_mode):
                 self.arena.data, out = self.engine.sync_step(
-                    self.arena.data, cohort, *self.step_data, arrived_w)
+                    *self._with_base(self.arena.data, cohort,
+                                     *self.step_data, arrived_w))
                 obs.inc("engine.sync_step")
+                if self.bundle.eval_metric == "loss":
+                    # every cohort slot trains, arrived or not
+                    obs.inc("engine.tokens", self._tokens_per_client
+                            * len(cohort))
                 obs.ready(out)
             if obs.enabled:
                 obs.compile_delta(self.engine.cache_sizes(), r)
@@ -634,8 +687,8 @@ class SimulatedFederation:
                 # materialises them at end of run).  Tracing blocks on them
                 # (timing attribution only — the values are unchanged).
                 with obs.span("round.eval", round=r):
-                    acc, cacc = self.engine.eval_cohort(
-                        out.new_rows, arrived_w, labels_dev, ex, ey)
+                    acc, cacc = self.engine.eval_cohort(*self._with_base(
+                        out.new_rows, arrived_w, labels_dev, ex, ey))
                     obs.ready(acc)
                 if obs.enabled:
                     obs.compile_delta(self.engine.cache_sizes(), r)

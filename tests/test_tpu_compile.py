@@ -73,6 +73,25 @@ def test_fingerprint_kernel_compiles_for_one_chip(shape, one_chip,
     assert "tpu_custom_call" in hlo
 
 
+def test_selective_scan_kernel_compiles_for_one_chip(one_chip,
+                                                    no_compile_cache):
+    """Jamba2-3B's scan at its widths (d_inner 5,120, d_state 16) over a
+    1,024-token row of an 8-client cohort, forward and backward."""
+    from repro.kernels.selective_scan import scan_pallas
+    B, S, di, n = 8, 1024, 5120, 16
+
+    def sd(shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    def loss(u, dl, a, b, c):
+        return jnp.sum(scan_pallas(u, dl, a, b, c) ** 2)
+
+    hlo = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        sd((B, S, di)), sd((B, S, di)), sd((n, di)), sd((B, S, n)),
+        sd((B, S, n))).compile().as_text()
+    assert hlo.count("tpu_custom_call") == 2
+
+
 def test_sync_step_compiles_for_one_chip_with_kernel(one_chip, monkeypatch,
                                                      no_compile_cache):
     """The engine's donated sync step at the default experiment shapes
