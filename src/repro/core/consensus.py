@@ -5,56 +5,53 @@ to its cluster's centroid (Eqs. 4–6) becomes that cluster's *representative*.
 Representatives join the DPoS-style packing queue: they take turns producing
 blocks and act as the aggregation client for their turn.
 
-Centroid selection is jittable; queue rotation is trivially host-side (it is
-consumed by the blockchain layer, `repro.blockchain`).
+Centroid selection and queue rotation are host-side NumPy: a round's
+Pearson matrix is at most a few hundred rows, so settling the chain needs no
+device program and no transfer (the queue is consumed by the blockchain layer,
+`repro.blockchain`).
 """
 from __future__ import annotations
 
-from functools import partial
 from typing import Collection, NamedTuple
 
-import jax
-import jax.numpy as jnp
+import numpy as np
 
 
 class CentroidResult(NamedTuple):
-    representatives: jax.Array   # (n_clusters,) client index per cluster, -1 if empty
-    distances: jax.Array         # (m,) distance of each client to its cluster centroid
-    centroids: jax.Array         # (n_clusters, m) mean Pearson row per cluster
+    representatives: np.ndarray  # (n_clusters,) int32 client index per cluster, -1 if empty
+    distances: np.ndarray        # (m,) float64 distance of each client to its cluster centroid
+    centroids: np.ndarray        # (n_clusters, m) float64 mean Pearson row per cluster
 
 
-@partial(jax.jit, static_argnames=("n_clusters",))
-def select_centroid_clients(corr: jax.Array, labels: jax.Array, n_clusters: int) -> CentroidResult:
-    """Paper Eqs. 4–6 on the Pearson matrix.
+def select_centroid_clients(corr, labels, n_clusters: int) -> CentroidResult:
+    """Paper Eqs. 4–6 on the Pearson matrix, in float64 on the host.
 
     Each client i is represented by its correlation profile Ξ[i, :] (the paper's
     𝔭 — "each point in the cluster").  The cluster centroid is the mean profile
     (Eq. 4); each member's Euclidean distance to it is Eq. 5–6; the argmin
-    member becomes the cluster's packing-queue representative.
+    member becomes the cluster's packing-queue representative (a tie goes to
+    the lowest index).  ``corr`` and ``labels`` may be NumPy or device arrays.
     """
-    m = corr.shape[0]
-    onehot = jax.nn.one_hot(labels, n_clusters, dtype=jnp.float32)          # (m, C)
-    counts = jnp.sum(onehot, axis=0)                                        # (C,)
-    sums = onehot.T @ corr.astype(jnp.float32)                              # (C, m)
-    centroids = sums / jnp.maximum(counts, 1.0)[:, None]                    # Eq. 4
+    corr = np.asarray(corr, np.float64)
+    labels = np.asarray(labels, np.int64)
+    member = labels[None, :] == np.arange(n_clusters)[:, None]            # (C, m)
+    counts = member.sum(axis=1)                                             # (C,)
+    centroids = (member @ corr) / np.maximum(counts, 1)[:, None]            # Eq. 4
 
-    diff = corr.astype(jnp.float32) - centroids[labels]                     # Eq. 5
-    dist = jnp.sqrt(jnp.sum(diff * diff, axis=1))                           # Eq. 6
+    diff = corr - centroids[labels]                                         # Eq. 5
+    dist = np.sqrt(np.sum(diff * diff, axis=1))                             # Eq. 6
 
     # per-cluster argmin over members only
-    big = jnp.finfo(jnp.float32).max
-    masked = jnp.where(onehot.T > 0, dist[None, :], big)                    # (C, m)
-    reps = jnp.argmin(masked, axis=1)
-    reps = jnp.where(counts > 0, reps, -1)
-    return CentroidResult(reps.astype(jnp.int32), dist, centroids)
+    reps = np.argmin(np.where(member, dist[None, :], np.inf), axis=1)
+    reps = np.where(counts > 0, reps, -1).astype(np.int32)
+    return CentroidResult(reps, dist, centroids)
 
 
-def packing_queue(representatives: jax.Array) -> list[int]:
-    """Host-side: ordered block-producer queue for the next epoch (empty
-    clusters dropped).  Order is cluster index — deterministic, so every
-    validator derives the same queue (DPoS slot schedule)."""
-    reps = [int(r) for r in jax.device_get(representatives)]
-    return [r for r in reps if r >= 0]
+def packing_queue(representatives) -> list[int]:
+    """Ordered block-producer queue for the next epoch (empty clusters
+    dropped).  Order is cluster index — deterministic, so every validator
+    derives the same queue (DPoS slot schedule)."""
+    return [int(r) for r in np.asarray(representatives) if r >= 0]
 
 
 def producer_for_round(queue: list[int], round_idx: int,

@@ -11,33 +11,34 @@ Properties (property-tested in tests/test_incentives.py):
 """
 from __future__ import annotations
 
-from functools import partial
 from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 
 class RewardAllocation(NamedTuple):
-    cluster_reward: jax.Array   # (C,) Γ(n_i)
-    client_reward: jax.Array    # (m,) r_k for every client
-    kappa: jax.Array            # scalar κ
-    fee: jax.Array              # scalar g = κ / N
+    cluster_reward: np.ndarray   # (C,) Γ(n_i)
+    client_reward: np.ndarray    # (m,) r_k for every client
+    kappa: np.float64            # scalar κ
+    fee: np.float64              # scalar g = κ / N
 
 
-@partial(jax.jit, static_argnames=("n_clusters", "total_reward", "rho"))
 def allocate_rewards(
-    labels: jax.Array,
+    labels,
     n_clusters: int,
     total_reward: float,
     rho: float = 2.0,
-    participating: jax.Array | None = None,
+    participating=None,
 ) -> RewardAllocation:
-    """Distribute the round's reward pool ℜ by cluster size.
+    """Distribute the round's reward pool ℜ by cluster size, in float64 on
+    the host (the ledger's precision).
 
-    ``labels``: (m,) cluster assignment from PAA. Empty clusters get Γ=0 and
-    do not absorb tokens (the denominator only sums over realised sizes, which
-    matches Σ n_i = N in the paper since empty clusters have n_i = 0).
+    ``labels``: (m,) cluster assignment from PAA, NumPy or a device array.
+    Empty clusters get Γ=0 and do not absorb tokens (the denominator only sums
+    over realised sizes, which matches Σ n_i = N in the paper since empty
+    clusters have n_i = 0).
 
     ``participating``: optional (m,) boolean/0-1 mask for partial-participation
     rounds (client sampling, stragglers, dropouts — ``repro.sim``).  Cluster
@@ -47,23 +48,21 @@ def allocate_rewards(
     update.  ``None`` (the paper's full-participation setting) keeps the
     original Eqs. 7–9 semantics unchanged.
     """
-    labels = labels.astype(jnp.int32)
-    m = labels.shape[0]
+    labels = np.asarray(labels, np.int64)
     if participating is None:
-        part = jnp.ones((m,), jnp.float32)
+        part = np.ones(labels.shape[0])
     else:
-        part = participating.astype(jnp.float32)
-    onehot = jax.nn.one_hot(labels, n_clusters, dtype=jnp.float32) * part[:, None]
-    sizes = jnp.sum(onehot, axis=0)
-    powered = jnp.where(sizes > 0, sizes ** rho, 0.0)
-    denom = jnp.sum(powered)
+        part = np.asarray(participating, np.float64)
+    sizes = np.bincount(labels, weights=part, minlength=n_clusters)
+    powered = np.where(sizes > 0, sizes ** rho, 0.0)
+    denom = powered.sum()
     # zero participants ⇒ zero pool (not total_reward / ε): callers that skip
     # the empty-round check must never see an astronomical κ or fee
-    kappa = jnp.where(denom > 0, total_reward / jnp.maximum(denom, 1e-12), 0.0)
+    kappa = np.float64(total_reward / denom if denom > 0 else 0.0)
     cluster_reward = kappa * powered                                  # Γ(n_i)
-    per_capita = cluster_reward / jnp.maximum(sizes, 1.0)             # Γ/n_i
+    per_capita = cluster_reward / np.maximum(sizes, 1.0)              # Γ/n_i
     client_reward = per_capita[labels] * part
-    fee = kappa / jnp.maximum(jnp.sum(part), 1.0)                     # Eq. 9
+    fee = kappa / max(part.sum(), 1.0)                                # Eq. 9
     return RewardAllocation(cluster_reward, client_reward, kappa, fee)
 
 

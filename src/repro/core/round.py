@@ -180,8 +180,8 @@ class FederatedTrainer:
         self,
         round_idx: int,
         local_params: Pytree | None,
-        labels: jax.Array,
-        corr: jax.Array,
+        labels: np.ndarray | jax.Array,
+        corr: np.ndarray | jax.Array,
         cohort: np.ndarray | None = None,
         arrived: np.ndarray | None = None,
         tamper: dict[int, str | Pytree] | None = None,
@@ -208,9 +208,14 @@ class FederatedTrainer:
         round engine (`repro.core.engine`) fingerprints the cohort inside its
         single jitted step, so the protocol here never touches params at all
         (``local_params`` may then be ``None``).
+
+        CACC and the reward split run in NumPy on the host: with NumPy
+        ``labels``, ``corr`` and ``digests`` the settlement dispatches no
+        device program and makes no transfer.  Device arrays are read back.
         """
         assert self.ledger is not None
-        k = int(np.asarray(labels).shape[0])
+        labels = np.asarray(labels)
+        k = labels.shape[0]
         cohort = np.arange(k) if cohort is None else np.asarray(cohort)
         arrived = np.ones(k, bool) if arrived is None else np.asarray(arrived, bool)
         n_total = self.ledger.n_clients
@@ -304,9 +309,9 @@ class FederatedTrainer:
         with obs.span("chain.rewards", cat="chain", round=round_idx):
             alloc = allocate_rewards(labels, self.n_clusters,
                                      self.total_reward, self.rho,
-                                     participating=jnp.asarray(arrived))
+                                     participating=arrived)
             rewards_total = np.zeros(n_total)
-            rewards_total[cohort] = np.asarray(alloc.client_reward)
+            rewards_total[cohort] = alloc.client_reward
             self.ledger.mint_reward_pool(self.total_reward)
             self.ledger.settle_round(rewards_total, float(alloc.fee),
                                      producer, verified_total)

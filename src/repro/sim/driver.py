@@ -631,13 +631,17 @@ class SimulatedFederation:
                 obs.ready(out)
             if obs.enabled:
                 obs.compile_delta(self.engine.cache_sizes(), r)
-            labels_dev, mean_loss = out.labels, out.mean_loss
+            mean_loss = out.mean_loss
             with obs.span("round.digests", round=r):
-                digests = self.engine.format_digests(out.residues)
+                # the chain's inputs in one read: the settlement below then
+                # runs on the host alone
+                residues, labels, corr = jax.device_get(
+                    (out.residues, out.labels, out.corr))
+                digests = self.engine.format_digests(residues)
             self.faults.maybe_crash(r, "pre_chain")
             with obs.span("round.chain", round=r):
                 cres = self.trainer.chain_round(
-                    r, None, labels_dev, out.corr, cohort=cohort,
+                    r, None, labels, corr, cohort=cohort,
                     arrived=arrived, tamper=self._tampers(cohort, arrived),
                     digests=digests)
         else:
@@ -649,11 +653,11 @@ class SimulatedFederation:
                 local_params, agg, mean_loss = self._cohort_round(
                     cohort_params, cx, cy, arrived_w)
                 obs.ready(mean_loss)
-            labels_dev = agg.labels
+            labels = np.asarray(agg.labels)
             self.faults.maybe_crash(r, "pre_chain")
             with obs.span("round.chain", round=r):
                 cres = self.trainer.chain_round(
-                    r, local_params, agg.labels, agg.corr, cohort=cohort,
+                    r, local_params, labels, agg.corr, cohort=cohort,
                     arrived=arrived, tamper=self._tampers(cohort, arrived))
 
             # arrived clients adopt their aggregated model; stragglers and
@@ -669,7 +673,6 @@ class SimulatedFederation:
 
         with obs.span("round.record", round=r):
             upd = np.asarray(cohort)[arrived]
-            labels = np.asarray(labels_dev)
             self.last_labels[upd] = labels[arrived]
 
             record.producer = cres.producer
@@ -688,7 +691,7 @@ class SimulatedFederation:
                 # (timing attribution only — the values are unchanged).
                 with obs.span("round.eval", round=r):
                     acc, cacc = self.engine.eval_cohort(*self._with_base(
-                        out.new_rows, arrived_w, labels_dev, ex, ey))
+                        out.new_rows, arrived_w, out.labels, ex, ey))
                     obs.ready(acc)
                 if obs.enabled:
                     obs.compile_delta(self.engine.cache_sizes(), r)
@@ -835,7 +838,7 @@ class SimulatedFederation:
 
         with obs.span("flush.prepare", cat="flush", round=version):
             # chain: single-cluster CACC over the flush group (host
-            # constants; the chain's jitted consumers take NumPy)
+            # constants: the chain settles on the host)
             labels = np.zeros(k, np.int32)
             corr = np.eye(k, dtype=np.float32)
             arrived = np.ones(k, dtype=bool)

@@ -60,6 +60,57 @@ def test_paper_rho2_example():
     np.testing.assert_allclose(float(alloc.fee), 0.5, rtol=1e-6)  # κ/N
 
 
+def _reference_rewards(labels, c, reward, rho, part):
+    """Eqs. 7–9 client by client in float64 over the participants."""
+    sizes = [sum(p for lab, p in zip(labels, part) if lab == tau)
+             for tau in range(c)]
+    powered = [n ** rho if n > 0 else 0.0 for n in sizes]
+    kappa = reward / sum(powered) if sum(powered) > 0 else 0.0
+    gamma = [kappa * pw for pw in powered]
+    client = [gamma[lab] / max(sizes[lab], 1.0) * p
+              for lab, p in zip(labels, part)]
+    return gamma, client, kappa, kappa / max(sum(part), 1.0)
+
+
+_REWARD_CASES = {
+    # name: (labels, n_clusters, rho, participating or None)
+    "full": ([0, 1, 2, 1, 0, 0, 2, 1, 1], 3, 2.0, None),
+    "empty_clusters": ([4, 0, 4, 4, 0, 4], 6, 1.5, None),
+    "non_participants": ([0, 0, 1, 1, 2, 2, 2], 3, 2.0,
+                         [1, 0, 1, 1, 0, 1, 1]),
+    "no_participants": ([0, 1, 1, 2], 3, 2.0, [0, 0, 0, 0]),
+    "flush_one_cluster": ([0] * 10, 1, 2.0, [1] * 10),
+}
+
+
+@pytest.mark.parametrize("case", list(_REWARD_CASES))
+def test_allocation_matches_float64_reference(case):
+    labels, c, rho, part = _REWARD_CASES[case]
+    mask = None if part is None else np.asarray(part, bool)
+    alloc = allocate_rewards(np.asarray(labels), c, 20.0, rho,
+                             participating=mask)
+    gamma, client, kappa, fee = _reference_rewards(
+        labels, c, 20.0, rho, [1.0] * len(labels) if part is None else part)
+    assert isinstance(alloc.cluster_reward, np.ndarray)
+    assert isinstance(alloc.client_reward, np.ndarray)
+    assert isinstance(alloc.kappa, np.float64)
+    assert isinstance(alloc.fee, np.float64)
+    np.testing.assert_allclose(alloc.cluster_reward, gamma, rtol=1e-12)
+    np.testing.assert_allclose(alloc.client_reward, client, rtol=1e-12)
+    np.testing.assert_allclose(alloc.kappa, kappa, rtol=1e-12)
+    np.testing.assert_allclose(alloc.fee, fee, rtol=1e-12)
+    if part is not None:
+        assert (alloc.client_reward[~mask] == 0.0).all()
+    if part is not None and not any(part):
+        assert alloc.kappa == 0.0 and alloc.fee == 0.0
+        assert not alloc.cluster_reward.any()
+    # device inputs give the same host result
+    dev = allocate_rewards(jnp.asarray(labels), c, 20.0, rho,
+                           participating=None if mask is None
+                           else jnp.asarray(mask))
+    np.testing.assert_array_equal(dev.client_reward, alloc.client_reward)
+
+
 def test_settlement_routes_fees_to_producer():
     labels = jnp.asarray([0, 0, 1, 1])
     alloc = allocate_rewards(labels, 2, 20.0, 2.0)

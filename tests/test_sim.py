@@ -1,10 +1,16 @@
 """Event-driven federation simulator: determinism, sampled-cohort reward
 conservation, straggler/dropout/Byzantine handling, async staleness weights."""
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.blockchain import TokenLedger
+from repro.core import FederatedTrainer, ModelBundle
+from repro.models import classifier as clf
+from repro.optim import adam
 from repro.sim import (
     BufferedAggregator,
     BufferedUpdate,
@@ -233,4 +239,34 @@ def test_chain_round_cohort_scatter():
     outside = np.ones(40, bool)
     outside[cohort] = False
     np.testing.assert_array_equal(delta[outside], 0.0)
+    assert tr.ledger.conserved()
+
+
+@pytest.mark.parametrize("k,n_clusters,absent", [
+    (10, 1, ()),                       # a FedBuff flush
+    (300, 5, (3, 17, 150, 299)),       # a sync round with no-shows
+], ids=["flush", "sync_round"])
+def test_chain_round_settles_on_host(k, n_clusters, absent):
+    """Given NumPy labels, Pearson matrix and digests, the settlement makes
+    no transfer in either direction: CACC and the reward split run on the
+    host."""
+    cfg = clf.MLPConfig(in_dim=8, hidden=(8,), rep_dim=4, num_classes=3)
+    bundle = ModelBundle(functools.partial(clf.apply, cfg),
+                         functools.partial(clf.embed, cfg), 3)
+    tr = FederatedTrainer(bundle, "fedavg", adam(1e-3), n_clusters=n_clusters)
+    n_total = 1000
+    tr.ledger = TokenLedger(n_total, tr.initial_stake)
+    rng = np.random.default_rng(k)
+    cohort = rng.choice(n_total, k, replace=False)
+    labels = rng.integers(0, n_clusters, k).astype(np.int32)
+    corr = rng.uniform(-1, 1, (k, k)).astype(np.float32)
+    arrived = np.ones(k, bool)
+    arrived[list(absent)] = False
+    digests = [f"{i:08x}{i * 7919 % 2**32:08x}{6570:08x}" for i in range(k)]
+    with jax.transfer_guard("disallow"):
+        res = tr.chain_round(0, None, labels, corr, cohort=cohort,
+                             arrived=arrived, digests=digests)
+    assert res.verified[arrived].all() and not res.verified[~arrived].any()
+    assert res.producer in set(cohort[arrived].tolist())
+    np.testing.assert_allclose(res.rewards.sum(), tr.total_reward, rtol=1e-12)
     assert tr.ledger.conserved()
